@@ -24,41 +24,15 @@ import jax as _jax
 # push f64 matmuls onto the MXU.
 _jax.config.update("jax_enable_x64", True)
 
-# Persistent XLA compilation cache: tessellation/join kernels compile
-# once per (pow2-bucketed) shape class; without a disk cache every new
-# process pays those compiles again (measured 7.1 s of an 18 s
-# real-zone tessellation).  Opt out with MOSAIC_TPU_NO_COMPILE_CACHE=1
-# or point elsewhere with MOSAIC_TPU_COMPILE_CACHE_DIR.
-import os as _os
+# Persistent XLA compilation cache for every process that imports the
+# package: tessellation/join kernels compile once per (pow2-bucketed)
+# shape class, and without a disk cache each new process pays those
+# compiles again (measured 7.1 s of an 18 s real-zone tessellation).
+# JAX_COMPILATION_CACHE_DIR wins when set; else the cache lives in the
+# checkout's .jax_cache/ (perf/jit_cache.py).
+from .perf.jit_cache import configure_persistent_cache as _cache
 
-if not _os.environ.get("MOSAIC_TPU_NO_COMPILE_CACHE"):
-    try:
-        # key the cache dir by a host fingerprint: XLA:CPU AOT results
-        # bake in machine features, and loading them on different
-        # hardware can SIGILL — a shared/migrated cache dir must not
-        # serve another machine's binaries
-        import hashlib as _hashlib
-        import platform as _platform
-        _fp = _platform.machine()
-        try:
-            with open("/proc/cpuinfo") as _f:
-                for _line in _f:
-                    if _line.startswith("flags"):
-                        _fp += _hashlib.sha256(
-                            _line.encode()).hexdigest()[:12]
-                        break
-        except OSError:
-            pass
-        _cache = _os.environ.get(
-            "MOSAIC_TPU_COMPILE_CACHE_DIR",
-            _os.path.join(_os.path.expanduser("~"), ".cache",
-                          "mosaic_tpu", f"xla-{_fp}"))
-        _os.makedirs(_cache, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", _cache)
-        _jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:                    # cache is an optimization only
-        pass
+_cache()
 
 from .config import MosaicConfig, default_config, set_default_config
 from .core.geometry.array import GeometryArray, GeometryBuilder, GeometryType

@@ -65,8 +65,8 @@ MOSAIC_EXACT_FALLBACK = "mosaic.exact.fallback"
 # null/zero-fill them — every codec threads this through.
 MOSAIC_IO_ON_ERROR = "mosaic.io.on.error"
 # Directory for JAX's persistent compilation cache (perf/jit_cache.py);
-# empty (the default) leaves the on-disk cache unconfigured.  Env var
-# MOSAIC_TPU_JIT_CACHE_DIR takes precedence over this key.
+# empty (the default) keeps the checkout's .jax_cache/.  Env var
+# JAX_COMPILATION_CACHE_DIR takes precedence over this key.
 MOSAIC_JIT_CACHE_DIR = "mosaic.jit.cache.dir"
 # Cadence (in calls/chunks) of the sharded join's per-shard skew
 # readback and placement refresh (parallel/pip_join.py,
@@ -283,9 +283,10 @@ class MosaicConfig:
     # Codec error policy (resilience/ingest.py): what a malformed
     # record/strip/message does — fail fast, get dropped, or get nulled.
     io_on_error: str = "raise"
-    # On-disk compiled-kernel cache directory; "" leaves it off.  When
-    # set (here or via MOSAIC_TPU_JIT_CACHE_DIR), warm-started
-    # processes load XLA executables from disk instead of recompiling.
+    # On-disk compiled-kernel cache directory; "" keeps the checkout's
+    # .jax_cache/.  JAX_COMPILATION_CACHE_DIR, when set, wins over it.
+    # Warm-started processes load XLA executables from disk instead of
+    # recompiling.
     jit_cache_dir: str = ""
     # Every K-th sharded-join call/chunk reads back per-shard matched
     # counts (one host sync), records shard/skew/* and refreshes the

@@ -64,6 +64,15 @@ def hex2d_to_ijk(xy: np.ndarray) -> np.ndarray:
     Cube rounding requires the 60°-basis axial frame (q, r) =
     (a - b, b); rounding the 120°-basis (a, b, -a-b) triple directly is
     only correct at lattice points (a bug this replaced)."""
+    _, _, rq, rr = _cube_round(xy)
+    a = (rq + rr).astype(np.int64)
+    b = rr.astype(np.int64)
+    return axial_to_ijk(a, b)
+
+
+def _cube_round(xy: np.ndarray):
+    """Axial (q, r) of hex2d points and (rq, rr) of the center of the
+    hexagon holding each, by cube rounding."""
     x = np.asarray(xy[..., 0], np.float64)
     y = np.asarray(xy[..., 1], np.float64)
     r = y / M_SIN60
@@ -75,9 +84,21 @@ def hex2d_to_ijk(xy: np.ndarray) -> np.ndarray:
     fix_r = ~fix_q & (dr > ds)
     rq = np.where(fix_q, -rr - rs, rq)
     rr = np.where(fix_r, -rq - rs, rr)
-    a = (rq + rr).astype(np.int64)
-    b = rr.astype(np.int64)
-    return axial_to_ijk(a, b)
+    return q, r, rq, rr
+
+
+def hex2d_margin(xy: np.ndarray) -> np.ndarray:
+    """Distance (lattice units) from each hex2d point to the boundary of
+    the hexagon holding it: the f64 host twin of the device kernels'
+    ``margin_lattice`` (core/index/h3/jaxkernel.py)."""
+    q, r, rq, rr = _cube_round(xy)
+    fq, fr = q - rq, r - rr
+    vx = fq + 0.5 * fr
+    sv = M_SIN60 * (M_SIN60 * fr)
+    h = 0.5 * vx
+    proj = np.maximum(np.abs(vx),
+                      np.maximum(np.abs(h + sv), np.abs(h - sv)))
+    return 0.5 - proj
 
 
 def ijk_sub(a: np.ndarray, b: np.ndarray) -> np.ndarray:

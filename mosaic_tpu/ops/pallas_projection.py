@@ -39,7 +39,10 @@ import numpy as np
 from ..core.index.h3.constants import M_SIN60
 from ..core.index.h3.hexmath import face_center_xyz, scaled_bases
 
-_BLOCK = 1024
+#: one grid step is a (_SUBLANES, _LANES) f32 block: the TPU compiler
+#: needs the last two block dims to be multiples of the (8, 128) tile
+_SUBLANES = 8
+_LANES = 1024
 
 
 # ---------------------------------------------- barrier-free df helpers
@@ -189,8 +192,9 @@ def _make_kernel(res: int, origin: Tuple[float, float]):
         def df_round(v):
             r = jnp.round(v[0])
             frac = (v[0] - r) + v[1]
-            adj = jnp.where(frac > 0.5, 1.0, 0.0) - \
-                jnp.where(frac < -0.5, 1.0, 0.0)
+            one, zero = np.float32(1.0), np.float32(0.0)
+            adj = jnp.where(frac > 0.5, one, zero) - \
+                jnp.where(frac < -0.5, one, zero)
             return r + adj, frac - adj
 
         rq, fq = df_round(qf)
@@ -230,29 +234,32 @@ def project_lattice_pallas(xy_local: jnp.ndarray, res: int,
                            interpret: bool = False):
     """Pallas version of jaxkernel._project_df (df path, localized
     input): [N, 2] local degrees -> (face, a, b, margin_lattice,
-    facegap).  N is padded internally to the block size."""
+    facegap).  N is padded internally to whole (8, 1024) blocks."""
     from jax.experimental import pallas as pl
 
     n = xy_local.shape[0]
-    nb = -(-max(n, 1) // _BLOCK)
-    pad = nb * _BLOCK - n
+    rows = _SUBLANES * -(-max(n, 1) // (_SUBLANES * _LANES))
+    pad = rows * _LANES - n
     x = jnp.pad(xy_local[:, 0].astype(jnp.float32), (0, pad))
     y = jnp.pad(xy_local[:, 1].astype(jnp.float32), (0, pad))
-    x = x.reshape(nb, _BLOCK)
-    y = y.reshape(nb, _BLOCK)
+    x = x.reshape(rows, _LANES)
+    y = y.reshape(rows, _LANES)
     kernel = _make_kernel(res, origin)
-    spec = pl.BlockSpec((1, _BLOCK), lambda i: (i, 0))
+    # int32 block indices: the package runs with x64 on, where a bare
+    # 0 would trace as int64, which the TPU lowering refuses
+    spec = pl.BlockSpec((_SUBLANES, _LANES),
+                        lambda i: (i, jnp.int32(0)))
     out = pl.pallas_call(
         kernel,
-        grid=(nb,),
+        grid=(rows // _SUBLANES,),
         in_specs=[spec, spec],
         out_specs=[spec] * 5,
         out_shape=[
-            jax.ShapeDtypeStruct((nb, _BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((nb, _BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((nb, _BLOCK), jnp.int32),
-            jax.ShapeDtypeStruct((nb, _BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((nb, _BLOCK), jnp.float32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(x, y)

@@ -318,10 +318,7 @@ def make_overlay_fn(ga: int, gb: int, edge_cap_a: int, edge_cap_b: int,
             lambda: jax.jit(fn))
 
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:      # moved in newer jax; older keeps it here
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     D = mesh.shape[axis]
     assert bucket_cap > 0, "sharded overlay needs a bucket capacity"
 
@@ -446,10 +443,7 @@ def make_overlay_pairs_fn(row_mult: int, edge_cap_a: int,
             lambda: jax.jit(fn))
 
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:      # moved in newer jax; older keeps it here
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     D = mesh.shape[axis]
     assert bucket_cap > 0
 

@@ -352,6 +352,7 @@ def make_streamed_pip_join(idx, grid: IndexSystem,
     ledger_key = (id(idx), id(grid), eps, margin_eps, precision)
 
     def run(points64: np.ndarray):
+        import time as _time
         from ..obs import metrics, tracer
         from ..obs.context import root_trace
         from ..obs.inflight import checkpoint
@@ -361,7 +362,7 @@ def make_streamed_pip_join(idx, grid: IndexSystem,
         points64 = np.asarray(points64, np.float64)[:, :2]
         n = len(points64)
         zone_out = np.empty(n, np.int32)
-        state = {"rechecked": 0}
+        state = {"rechecked": 0, "recheck_s": 0.0}
 
         def put(sl):
             # f64 origin shift BEFORE the f32 cast (= localize());
@@ -371,7 +372,9 @@ def make_streamed_pip_join(idx, grid: IndexSystem,
 
         def consume(i, sl, host):
             z, unc = host
+            t0 = _time.perf_counter()
             zone_out[sl] = recheck(points64[sl], z, unc)
+            state["recheck_s"] += _time.perf_counter() - t0
             state["rechecked"] += int(unc.sum())
 
         def observe(i, sl, seconds):
@@ -383,6 +386,9 @@ def make_streamed_pip_join(idx, grid: IndexSystem,
                    consume=consume, observe=observe,
                    site="pip_join/streamed")
         if metrics.enabled:
+            # host f64 recheck seconds, overlapped with the device by
+            # the pipeline's worker thread
+            metrics.count("pip_join/recheck_s", state["recheck_s"])
             metrics.count("pip_join/streamed_points", float(n))
             metrics.count("pip_join/streamed_chunks",
                           float(-(-n // chunk) if n else 0))
@@ -586,7 +592,16 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
             state["slots"][sl.start] = slots
             # device_put against the sharding splits the buffer across
             # the mesh asynchronously, overlapping the running launch
-            return per * D, jax.device_put(buf, pts_sharding)
+            dev = jax.device_put(buf, pts_sharding)
+            if metrics.enabled:
+                # where the rows landed, read off the staged array's own
+                # shards (padding rows included)
+                for sh in dev.addressable_shards:
+                    d = sh.device
+                    metrics.count("shard/staged_rows/pip_join/"
+                                  f"{d.platform}:{d.id}",
+                                  float(sh.data.shape[0]))
+            return per * D, dev
 
         def compute(staged):
             rows, dev = staged
@@ -1114,8 +1129,10 @@ def make_refined_pip_join(polys: GeometryArray, grid: IndexSystem,
                     lambda: jax.jit(
                         lambda p: grid.point_to_cell_jax(p, res)))
                 return np.asarray(fn(jnp.asarray(buf)))[:rows]
-            except Exception:       # host-only grid: route there instead
+            except NotImplementedError:     # host-only grid
                 state["route_host"] = True
+                if metrics.enabled:
+                    metrics.count("pip_join/route_host")
         return grid.point_to_cell(pts64, res)
 
     def _probe(points64: np.ndarray) -> None:
@@ -1442,13 +1459,16 @@ class DensePIPIndex:
         return int(self.pool.shape[0])
 
 
-def _host_lattice(grid, pts_deg: np.ndarray, res: int):
-    """f64 (face, a, b) of absolute lon/lat degree points (host truth)."""
+def _host_lattice(grid, pts_deg: np.ndarray, res: int,
+                  with_margin: bool = False):
+    """f64 (face, a, b) of absolute lon/lat degree points (host truth),
+    plus each point's lattice margin to its cell boundary when asked."""
     from ..core.index.h3 import hexmath as hm
     latlng = np.radians(np.asarray(pts_deg, np.float64)[:, ::-1])
     face, hex2d = hm.project_lattice(latlng, res)
     ijk = hm.hex2d_to_ijk(hex2d)
-    return face, ijk[:, 0] - ijk[:, 2], ijk[:, 1] - ijk[:, 2]
+    out = (face, ijk[:, 0] - ijk[:, 2], ijk[:, 1] - ijk[:, 2])
+    return out + (hm.hex2d_margin(hex2d),) if with_margin else out
 
 
 #: why the last build_dense_pip_index call fell back (None = it
@@ -1629,13 +1649,14 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
     from ..core.index.h3.constants import M_SQRT7, RES0_U_GNOMONIC
     sag_deg = grid.cells_edge_sagitta_deg(cells) if hasattr(
         grid, "cells_edge_sagitta_deg") else 0.0
-    err = max(err, 2.0 * np.radians(sag_deg) * M_SQRT7 ** res /
-              RES0_U_GNOMONIC)
+    sag_lattice = 2.0 * np.radians(sag_deg) * M_SQRT7 ** res / \
+        RES0_U_GNOMONIC
+    err = max(err, sag_lattice)
     aux = {
         "flat_a": flat_a, "flat_b": flat_b,
         "edge_zslot": edge_zslot.astype(np.int64),
         "gstart": gstart, "gzones64": gzones.astype(np.int64),
-        "grid": grid, "polys": polys,
+        "grid": grid, "polys": polys, "sag_lattice": sag_lattice,
     }
     return DensePIPIndex(
         entry=jnp.asarray(entry), pool=jnp.asarray(pool),
@@ -1757,9 +1778,10 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
     reruns the flagged points through the SAME chip semantics in f64 —
     exact cell assignment (host lattice), exact crossing parity against
     the original unquantized chip edges.  Replaces the per-polygon
-    Python loop (round-2 host_recheck) that VERDICT.md flagged as
-    unscalable: this is a handful of numpy passes over the flagged
-    subset.
+    Python loop (round-2 host_recheck), which did not scale: this is a
+    handful of numpy passes over the flagged subset.  Flagged points
+    inside the cell-edge sagitta band go to the original polygons
+    instead (see ``recheck`` below).
 
     For a sorted ``PIPIndex`` (no dense aux tables) the recheck
     authority is the original polygons — pass ``polys``; the returned
@@ -1792,6 +1814,7 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
         ezslot_native = aux["edge_zslot"].astype(np.int32)
         gzones_native = np.ascontiguousarray(
             aux["gzones64"].astype(np.int32))
+    truth = _host_truth_fn(aux["polys"])
 
     def recheck(points64: np.ndarray, zone: np.ndarray,
                 uncertain: np.ndarray) -> np.ndarray:
@@ -1800,13 +1823,28 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
             return zone
         zone = np.asarray(zone).copy()
         pts = np.asarray(points64)[sel]
-        face, a, b = _host_lattice(aux["grid"], pts, idx.res)
+        face, a, b, margin = _host_lattice(aux["grid"], pts, idx.res,
+                                           with_margin=True)
+        out = _chip_zones(pts, face, a, b)
+        # a point within the cell-edge sagitta band can sit in its H3
+        # cell (true gnomonic edges) yet outside that cell's chips
+        # (clipped against straight lon/lat chords): the original
+        # polygons decide those
+        band = margin < aux["sag_lattice"]
+        if band.any():
+            out[band] = truth(pts[band])
+        zone[sel] = out
+        return zone
+
+    def _chip_zones(pts, face, a, b) -> np.ndarray:
+        """Zone of each point by exact f64 parity against the chips of
+        its host-lattice cell."""
         ia = a - idx.a0
         ib = b - idx.b0
         inw = ((face == idx.face0) & (ia >= 0) & (ia < idx.W) &
                (ib >= 0) & (ib < idx.H))
         e = np.where(inw, entry[np.where(inw, ia * idx.H + ib, 0)], -1)
-        out = np.full(len(sel), -1, np.int32)
+        out = np.full(len(pts), -1, np.int32)
         is_core = (e >= 0) & ((e & int(CORE_FLAG)) != 0)
         out[is_core] = (e[is_core] & ~int(CORE_FLAG))
 
@@ -1815,15 +1853,14 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
         if len(bsel):
             # native chip-parity core when the C++ layer is available
             if _native is not None:
-                grp = np.full(len(sel), -1, np.int64)
+                grp = np.full(len(pts), -1, np.int64)
                 grp[bsel] = e[bsel]
                 nz = _native.recheck_zones(
                     pts, grp, flat_native, ezslot_native,
                     aux["gstart"], gzones_native)
                 if nz is not None:
                     out[bsel] = nz[bsel]
-                    zone[sel] = out
-                    return zone
+                    return out
             g = e[bsel].astype(np.int64)
             gstart = aux["gstart"]
             cnt = (gstart[g + 1] - gstart[g]).astype(np.int64)
@@ -1850,8 +1887,7 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
             first = odd.argmax(axis=1)
             gz = aux["gzones64"][g, first]
             out[bsel[anyin]] = gz[anyin].astype(np.int32)
-        zone[sel] = out
-        return zone
+        return out
 
     return recheck
 
@@ -1865,27 +1901,39 @@ def pip_host_truth(points64: np.ndarray,
     Routes through the native C++ kernel (mosaic_tpu.native, the
     JTS/GEOS-analogue layer) when the toolchain built it — bit-identical
     crossing rule — and falls back to the numpy broadcast loop."""
+    return _host_truth_fn(polys)(points64)
+
+
+def _host_truth_fn(polys: GeometryArray):
+    """:func:`pip_host_truth` bound to ``polys``: the polygon edges are
+    prepared once, so repeated calls on small point sets stay cheap."""
     from ..core.tessellate import _pip, _poly_edges
     edges_list = [_poly_edges(polys, gi) for gi in range(len(polys))]
     try:
         from .. import native
     except ImportError:
         native = None
+    flat = gs = None
     if native is not None and len(polys):
         gs = np.zeros(len(polys) + 1, np.int64)
         np.cumsum([len(e) for e in edges_list], out=gs[1:])
         flat = np.concatenate(edges_list).reshape(-1, 4)
-        # unavailability is signalled by None (no compiler); real
-        # errors must raise, not silently fall back to the slow path
-        out = native.pip_first_match(np.asarray(points64)[:, :2], flat,
-                                     gs)
-        if out is not None:
-            return out
-    truth = np.full(len(points64), -1, np.int32)
-    for gi in range(len(polys)):
-        inside = _pip(points64, edges_list[gi])
-        truth = np.where((truth < 0) & inside, gi, truth)
-    return truth
+
+    def truth_of(points64: np.ndarray) -> np.ndarray:
+        if flat is not None:
+            # unavailability is signalled by None (no compiler); real
+            # errors must raise, not silently fall back to the slow path
+            out = native.pip_first_match(np.asarray(points64)[:, :2],
+                                         flat, gs)
+            if out is not None:
+                return out
+        truth = np.full(len(points64), -1, np.int32)
+        for gi in range(len(polys)):
+            inside = _pip(points64, edges_list[gi])
+            truth = np.where((truth < 0) & inside, gi, truth)
+        return truth
+
+    return truth_of
 
 
 def host_recheck(points64: np.ndarray, zone: np.ndarray,

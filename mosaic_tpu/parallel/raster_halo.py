@@ -32,10 +32,7 @@ def _convolve_fn(kernel: np.ndarray, mesh, axis: str, shape):
     ``shape`` = (bands, H, W) (cached in the process kernel cache)."""
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:      # moved in newer jax; older keeps it here
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     k = np.asarray(kernel, np.float64)

@@ -11,8 +11,9 @@ applied to the chipping/join hot path:
 * ``perf.jit_cache`` — the process-level compiled-kernel LRU unifying
   the ad-hoc ``dict`` caches that had grown in ``core/tessellate.py``,
   ``models/knn.py`` and ``parallel/raster_halo.py``, plus the wiring
-  for JAX's **persistent** compilation cache (conf key
-  ``mosaic.jit.cache.dir`` / env ``MOSAIC_TPU_JIT_CACHE_DIR``) so the
+  for JAX's **persistent** compilation cache (env
+  ``JAX_COMPILATION_CACHE_DIR``, else conf key ``mosaic.jit.cache.dir``,
+  else the checkout's ``.jax_cache/``) so the
   first-call compile cost vanishes on warm starts.  Hit/miss/eviction
   counters land in ``obs.metrics`` under ``perf/jit_cache/*``.
 * ``perf.pipeline`` — a double-buffered chunk executor: host→device

@@ -41,8 +41,16 @@ from ..obs.metrics import metrics
 __all__ = ["JitCache", "kernel_cache", "configure_persistent_cache",
            "persistent_cache_dir"]
 
-#: env var mirroring the ``mosaic.jit.cache.dir`` conf key
-JIT_CACHE_DIR_ENV = "MOSAIC_TPU_JIT_CACHE_DIR"
+#: JAX's own cache-directory variable: when it is set, JAX reads it
+#: itself and this module sets no directory in code
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the cache lives when neither the variable nor the
+#: ``mosaic.jit.cache.dir`` conf key places it: a fixed path inside the
+#: checkout (the path is part of the cache key, so it must not move)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 class JitCache:
@@ -159,16 +167,17 @@ def persistent_cache_dir() -> Optional[str]:
     return _persist_dir
 
 
-def configure_persistent_cache(path: Optional[str] = None
-                               ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path``.
+def configure_persistent_cache(path: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache.
 
-    Resolution order: explicit argument > ``MOSAIC_TPU_JIT_CACHE_DIR``
-    env > the active config's ``mosaic.jit.cache.dir``.  Returns the
-    resolved directory, or None when nothing is configured (a no-op —
-    the in-memory caches still work).  Idempotent; re-pointing at a
-    different directory is honored (last call wins) but logged to the
-    flight recorder either way.
+    Resolution order: ``JAX_COMPILATION_CACHE_DIR`` (JAX reads it
+    itself; no directory is set in code) > explicit argument (the
+    ``mosaic.jit.cache.dir`` conf key passes one) >
+    :data:`CHECKOUT_CACHE_DIR`.  Importing the package calls this once,
+    so every process has the cache.  Returns the directory in use.
+    Idempotent; re-pointing at a different directory is honored (last
+    call wins) and logged to the flight recorder.  JAX creates the
+    directory at its first write.
 
     Thresholds are dropped so EVERY compile persists
     (``min_entry_size_bytes=-1``, ``min_compile_time_secs=0``): this
@@ -178,20 +187,14 @@ def configure_persistent_cache(path: Optional[str] = None
     sharing the directory — the cache key hashes compile options, so
     drift turns hits into misses."""
     global _persist_dir
-    if path is None:
-        path = os.environ.get(JIT_CACHE_DIR_ENV)
-    if path is None:
-        from ..config import default_config
-        path = getattr(default_config(), "jit_cache_dir", "") or None
-    if not path:
-        return _persist_dir
-    path = str(path)
+    import jax
+    env = os.environ.get(JAX_CACHE_DIR_ENV)
+    path = str(env or path or CHECKOUT_CACHE_DIR)
     with _persist_lock:
         if _persist_dir == path:
             return _persist_dir
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not env:
+            jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                           -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",

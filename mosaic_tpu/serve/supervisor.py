@@ -98,6 +98,19 @@ def _reuse_port_supported() -> bool:
     return hasattr(socket, "SO_REUSEPORT")
 
 
+def _parent_holds_tpu() -> bool:
+    """True when this process has brought up a TPU backend: the TPU
+    runtime then keeps the chip until the process exits.  Never brings
+    up a backend itself."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 class WorkerSlot:
     """One worker position in the fleet: the live process (if any),
     its restart history inside the breaker window, and the respawn
@@ -184,6 +197,14 @@ class ServeFleet:
     # -- lifecycle -----------------------------------------------------
     def start(self, wait_ready: bool = True,
               ready_timeout_s: float = 90.0) -> "ServeFleet":
+        if _parent_holds_tpu() and \
+                os.environ.get("JAX_PLATFORMS", "").strip() != "cpu":
+            raise RuntimeError(
+                "ServeFleet: this process holds the TPU, so workers that "
+                "need it would fail on the TPU runtime's lock or hang "
+                "until the ready timeout; start the fleet from a process "
+                "that has not brought up JAX, or run the workers with "
+                "JAX_PLATFORMS=cpu")
         with self._lock:
             if self._started:
                 return self

@@ -106,3 +106,31 @@ def test_multiface_falls_back_to_sorted():
     grid = get_index_system("H3")
     idx = build_pip_index(polys, 2, grid)
     assert isinstance(idx, PIPIndex)
+
+
+def test_dense_recheck_exact_in_cell_edge_sagitta_band(workload,
+                                                        dense_idx):
+    """Points between a cell's straight lon/lat chord (which its chips
+    are clipped against) and its true gnomonic edge (which assigns
+    them) sit in their H3 cell yet outside its chips: the recheck must
+    answer them from the original polygons, not the chips."""
+    polys, grid, res = workload
+    cells = np.unique(grid.point_to_cell(nyc_points(4_000, seed=5), res))
+    verts, counts = grid.cell_boundary(cells)
+    sag = grid.cells_edge_sagitta_deg(cells)
+    pts = []
+    for v, k in zip(verts, counts):
+        a = v[:k]
+        b = np.roll(a, -1, axis=0)
+        d = b - a
+        normal = np.stack([d[:, 1], -d[:, 0]], -1)
+        normal /= np.linalg.norm(normal, axis=1, keepdims=True)
+        for t in np.linspace(-2.0, 2.0, 9) * sag:
+            pts.append(0.5 * (a + b) + t * normal)
+    pts64 = np.concatenate(pts)
+    fn = jax.jit(make_pip_join_fn(dense_idx, grid))
+    zone, unc = fn(jnp.asarray(localize(dense_idx, pts64)))
+    final = host_recheck_fn(dense_idx)(pts64, np.asarray(zone),
+                                       np.asarray(unc))
+    truth = pip_host_truth(pts64, polys)
+    assert np.array_equal(final, truth), int(np.sum(final != truth))

@@ -229,6 +229,46 @@ def test_migrated_kernels_warm_zero_compiles():
         "H3 sample kernel recompiled per instance"
 
 
+@pytest.mark.parametrize("env, arg, want", [
+    ("from-env", "from-conf", "from-env"),     # JAX's variable wins
+    (None, "from-conf", "from-conf"),          # then the conf key
+    (None, None, None),                        # then the checkout
+])
+def test_persistent_cache_resolution(tmp_path, monkeypatch, env, arg,
+                                     want):
+    """One resolution: JAX_COMPILATION_CACHE_DIR (the package then sets
+    no directory in code), else an explicit/conf directory, else the
+    checkout's fixed .jax_cache."""
+    import os
+    import jax
+    from mosaic_tpu.perf import jit_cache as jc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs")
+    prev = [getattr(jax.config, k) for k in keys]
+    prev_dir = jc._persist_dir
+    jc._persist_dir = None
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = jc.configure_persistent_cache(
+            str(tmp_path / arg) if arg else None)
+        expect = str(tmp_path / want) if want else jc.CHECKOUT_CACHE_DIR
+        assert got == expect == jc.persistent_cache_dir()
+        assert jax.config.jax_compilation_cache_dir == (
+            prev[0] if env else expect)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in zip(keys, prev):
+            jax.config.update(k, v)
+        jc._persist_dir = prev_dir
+    assert jc.CHECKOUT_CACHE_DIR == os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
 # ---------------------------------------------------------- pipeline
 
 def test_chunk_rows():

@@ -93,6 +93,29 @@ def test_sharded_streamed_parity(workload):
     assert np.array_equal(z_ref, pip_host_truth(pts64, polys))
 
 
+def test_sharded_streamed_staged_rows_per_device(workload):
+    """The sharded join counts the rows each device was handed, read off
+    the staged arrays' own shards: every chunk splits evenly, padding
+    included (4096 -> 1024 per device, the 1845-row tail -> 512)."""
+    from mosaic_tpu.obs import metrics
+    polys, grid, res, idx = workload
+    mesh = _mesh4()
+    shj = make_sharded_streamed_pip_join(idx, grid, mesh, polys=polys,
+                                         chunk=4096)
+    keys = [f"shard/staged_rows/pip_join/{d.platform}:{d.id}"
+            for d in mesh.devices.flat]
+    was = metrics.enabled
+    metrics.enable()
+    try:
+        before = [metrics.counter_value(k) for k in keys]
+        shj(nyc_points(10_037, seed=9))
+        rows = [metrics.counter_value(k) - b for k, b in zip(keys, before)]
+    finally:
+        if not was:
+            metrics.disable()
+    assert rows == [1024 + 1024 + 512] * 4
+
+
 def _skewed_cloud(polys, n=4096, frac=0.9, seed=21):
     """90% of points uniform inside zone 0's box, 10% just west of the
     workload bbox (unmatched, zone -1), cluster-first row order — the

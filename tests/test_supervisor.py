@@ -119,6 +119,27 @@ def test_no_worker_ready_raises(tmp_path, fleet_env):
         fleet.start(ready_timeout_s=10)
 
 
+@pytest.mark.parametrize("platforms, raises", [("", True),
+                                                ("cpu", False)])
+def test_parent_holding_tpu_fails_fast(tmp_path, fleet_env, monkeypatch,
+                                       platforms, raises):
+    """A parent that holds the chip refuses to spawn workers that would
+    need it, unless they are pinned to the CPU."""
+    from mosaic_tpu.serve import supervisor as sup
+    assert not sup._parent_holds_tpu()        # this suite runs on CPU
+    _conf(mosaic_serve_fleet_health_ms=0)
+    monkeypatch.setattr(sup, "_parent_holds_tpu", lambda: True)
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    fleet = _fleet(tmp_path, workers=1)
+    if raises:
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            fleet.start()
+        assert fleet.worker_pids() == []
+    else:
+        with fleet:
+            assert len(fleet.worker_pids()) == 1
+
+
 def test_parent_socket_fallback_mode(tmp_path, fleet_env):
     _conf(mosaic_serve_fleet_health_ms=0)
     with _fleet(tmp_path, workers=1,
