@@ -1,0 +1,35 @@
+"""The streamed entry's stage counters reach the result line of a traced
+run, and only of a traced one (CPU rehearsal at a tiny size: no number
+here is a device number)."""
+
+import json
+import time
+
+import harness
+
+CELL = "nyc_taxi_h3r9.bulk"
+TINY = {"points_per_request": 1 << 16}
+SEED = 2**31 + 78
+STREAM = {"stream_head_ms", "stream_tail_ms", "stream_put_ms",
+          "dispatch_wait_ms"}
+
+
+def rehearse(trace):
+    out = harness.run_cell(CELL, SEED, 1.0, trace, time.perf_counter(),
+                           require_chip=False, overrides=TINY)
+    return json.loads(json.dumps(out))
+
+
+def test_traced_run_reports_stream_stages():
+    line = rehearse(trace=True)
+    assert line["correct"] is True
+    assert STREAM <= set(line["metrics"])
+    for name in STREAM:
+        m = line["metrics"][name]
+        assert m["unit"] == "ms" and m["value"] > 0, name
+
+
+def test_untraced_run_reports_end_to_end_only():
+    line = rehearse(trace=False)
+    assert line["correct"] is True
+    assert set(line["metrics"]) == {"setup_s", "join_pts_per_s"}

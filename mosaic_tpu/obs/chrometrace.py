@@ -13,9 +13,10 @@ any trace fall back to one lane per OS thread.  Each ``X`` event's
 ``args`` carry the span/parent ids, the trace id, the real native
 thread id, and the error (if the span body raised).
 
-Typical use: capture a device timeline with ``obs.device_trace`` while
-the host tracer runs, then lay this export beside the xprof capture to
-line host stages up with device activity.
+This export is on the tracer's own clock.  To line host stages up with
+device activity, capture with ``obs.device_trace`` instead: the same
+spans land in that timeline as ``mosaic/<span>`` host events, on the
+profiler's clock.
 """
 
 from __future__ import annotations

@@ -16,6 +16,12 @@ supply the equivalent surface ourselves:
   or ``MOSAIC_TPU_TRACE=1``.  ``MosaicContext.call`` wraps every by-name
   dispatch in a span, so external engines driving the string surface get
   per-function wall times for free.
+* **Profiler annotations** — while a ``jax.profiler`` session is
+  recording, every span (tracer enabled or not) also enters a
+  ``jax.profiler.TraceAnnotation("mosaic/<name>")``, so program stages
+  land in the device trace on the profiler's own clock, beside the
+  device ops.  The check is one static ``TraceMe`` probe, made only
+  once JAX is imported; the tracer itself never imports JAX.
 * **Trace-scoped span trees** — the span stack lives in a
   ``contextvars.ContextVar`` (not a thread-local), so it follows the
   active :class:`~mosaic_tpu.obs.context.TraceContext`: every completed
@@ -26,9 +32,8 @@ supply the equivalent surface ourselves:
   raster operators stamp what ran (and what failed) into ``tile.meta``;
   both also bump registry counters so fleet-wide rates are visible.
 * ``device_trace`` — context manager around ``jax.profiler.trace`` for
-  XLA/TPU timeline captures (inspect with tensorboard or xprof; lay the
-  Chrome-trace export of host spans beside it to line host stages up
-  with device activity).
+  XLA/TPU timeline captures (inspect with tensorboard or xprof; the
+  program's spans appear in it as ``mosaic/<name>`` host events).
 
 ``tracer.enable()`` also enables the metrics registry (span call-sites
 feed counters/gauges into it); ``disable()`` turns the registry back off
@@ -43,6 +48,7 @@ import collections
 import contextlib
 import contextvars
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -61,6 +67,21 @@ _MAX_EVENTS = 100_000   # bounded Chrome-trace ring (~10 MB of JSON)
 #: follows the trace context across threads and executors.
 _SPAN_STACK: "contextvars.ContextVar[Tuple[Tuple[str, int], ...]]" = \
     contextvars.ContextVar("mosaic_span_stack", default=())
+
+
+#: what ``Tracer.span`` returns with the tracer off and no profiler
+#: recording (a ``nullcontext`` is reusable and reentrant)
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _profiler_annotation(name: str):
+    """A ``TraceAnnotation("mosaic/<name>")`` while a ``jax.profiler``
+    session is recording, else None.  Looks JAX up in ``sys.modules``:
+    the tracer never imports it."""
+    prof = sys.modules.get("jax.profiler")
+    cls = getattr(prof, "TraceAnnotation", None)   # None mid-import too
+    return cls("mosaic/" + name) if cls is not None and cls.is_enabled() \
+        else None
 
 
 class SpanEvent(NamedTuple):
@@ -91,7 +112,7 @@ class _Span:
 
 class Tracer:
     """Span wall-times + named counters, thread-safe, ~zero cost when
-    disabled (one attribute check per span)."""
+    disabled (one attribute check and one profiler probe per span)."""
 
     def __init__(self):
         self._enabled = bool(os.environ.get("MOSAIC_TPU_TRACE"))
@@ -127,11 +148,20 @@ class Tracer:
         metrics.reset()
 
     # -- spans
-    @contextlib.contextmanager
     def span(self, name: str):
+        """Context manager timing its body as span ``name``."""
+        ann = _profiler_annotation(name)
+        if ann is None and not self._enabled:
+            return _NO_SPAN
         if not self._enabled:
-            yield
-            return
+            return ann
+        return self._recorded(name, ann)
+
+    @contextlib.contextmanager
+    def _recorded(self, name: str, ann):
+        """A span of the enabled tracer: stack, ring, histogram and
+        flight-recorder event, around the profiler annotation ``ann``
+        (None when no profiler is recording)."""
         stack = _SPAN_STACK.get()
         sid = next_span_id()
         parent = stack[-1][1] if stack else None
@@ -140,7 +170,8 @@ class Tracer:
         t0 = time.perf_counter()
         err: Optional[str] = None
         try:
-            yield
+            with ann if ann is not None else _NO_SPAN:
+                yield
         except BaseException as e:
             err = f"{type(e).__name__}: {e}"[:200]
             raise
@@ -270,9 +301,17 @@ def record_error(tile, err: BaseException) -> None:
 @contextlib.contextmanager
 def device_trace(logdir: str, host_tracer_level: int = 2):
     """Capture an XLA/TPU profiler timeline into ``logdir`` (reference
-    analogue: the Spark UI stage timeline).  View with xprof/tensorboard."""
+    analogue: the Spark UI stage timeline).  View with xprof/tensorboard.
+
+    ``host_tracer_level`` is the profiler's host level: 1 keeps user
+    annotations only (the program's ``mosaic/*`` spans among them), 2
+    adds the runtime's own host events, 3 is verbose.  The Python
+    function tracer stays off."""
     import jax
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = host_tracer_level
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
         yield logdir
     finally:

@@ -289,7 +289,7 @@ def make_pip_join_fn(idx, grid: IndexSystem, eps: Optional[float] = None,
         # 57x and missed high-latitude cells — round-4 review)
         margin_eps = max(3e-5, 2.0 * idx.sagitta_deg)
 
-    def fn(points: jnp.ndarray):
+    def pip_sorted_join(points: jnp.ndarray):
         absolute = points + idx.origin.astype(points.dtype)
         cells, margin = grid.point_to_cell_jax_margin(absolute, idx.res)
         zone, uncertain = pip_assign(points, cells, idx, eps)
@@ -307,7 +307,7 @@ def make_pip_join_fn(idx, grid: IndexSystem, eps: Optional[float] = None,
                     absolute + off) != inb
         return jnp.where(inb, zone, jnp.int32(-1)), uncertain | near_edge
 
-    return fn
+    return pip_sorted_join
 
 
 def _resolve_chunk(chunk: Optional[int]) -> int:
@@ -389,9 +389,6 @@ def make_streamed_pip_join(idx, grid: IndexSystem,
             # host f64 recheck seconds, overlapped with the device by
             # the pipeline's worker thread
             metrics.count("pip_join/recheck_s", state["recheck_s"])
-            metrics.count("pip_join/streamed_points", float(n))
-            metrics.count("pip_join/streamed_chunks",
-                          float(-(-n // chunk) if n else 0))
         return zone_out, state["rechecked"]
 
     return run
@@ -570,6 +567,7 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
                             out_shardings=out_sharding))
 
     def run(points64: np.ndarray):
+        import time as _time
         from ..obs import tracer
         from ..obs.context import root_trace
         from ..obs.inflight import checkpoint
@@ -577,7 +575,7 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
         points64 = np.asarray(points64, np.float64)[:, :2]
         n = len(points64)
         zone_out = np.empty(n, np.int32)
-        state = {"rechecked": 0, "slots": {}}
+        state = {"rechecked": 0, "slots": {}, "recheck_s": 0.0}
 
         def put(sl):
             rows = sl.stop - sl.start
@@ -613,7 +611,9 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
             slots = state["slots"].pop(sl.start)
             z = zp[slots]
             unc = np.asarray(up)[slots]
+            t0 = _time.perf_counter()
             zone_out[sl] = recheck(points64[sl], z, unc)
+            state["recheck_s"] += _time.perf_counter() - t0
             state["rechecked"] += int(unc.sum())
             # feedback is free here — the shard results are already on
             # host, unlike the monolithic path's cadenced device sync
@@ -635,7 +635,6 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
                            (id(idx), id(mesh), axis, padded, eps,
                             margin_eps), seconds, rows=rows)
 
-        import time as _time
         t0 = _time.perf_counter()
         with root_trace("pip_join"), \
                 tracer.span("pip_join/sharded_streamed"):
@@ -655,9 +654,8 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
                           float(idx_bytes) * D)
             metrics.gauge("shard/points_per_shard/pip_join", n / D)
             metrics.count("collective/points_scatter_bytes", 8.0 * n)
-            metrics.count("pip_join/sharded_points", float(n))
-            metrics.count("pip_join/sharded_chunks",
-                          float(-(-n // chunk) if n else 0))
+            # host f64 recheck seconds, as on the single-chip path
+            metrics.count("pip_join/recheck_s", state["recheck_s"])
         return zone_out, state["rechecked"]
 
     run.rebalancer = rebalancer
@@ -1707,68 +1705,75 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
         err_lat = max(err_lat, err_lattice_bound(
             idx.res, "df", idx.ext_deg, localized=True))
 
-    def fn(points):
-        if use_pallas:
-            # opt-in Pallas projection kernel (ops/pallas_projection.py)
-            # until validated on hardware; same contract, same outputs
-            from ..ops.pallas_projection import project_lattice_pallas
-            face, ai, bi, margin, facegap = project_lattice_pallas(
-                points, idx.res,
-                # graftlint: ignore[jit-host-sync] — idx.origin is a host-side numpy constant closed over, folds at trace time
-                (float(idx.origin[0]), float(idx.origin[1])))
-        else:
-            face, ai, bi, margin, facegap = project_lattice_jax(
-                points, idx.res, idx.origin, precision=precision)
-        far = (jnp.abs(points[..., 0]) > far_lim) | \
-            (jnp.abs(points[..., 1]) > far_lim)
-        ia = ai - idx.a0
-        ib = bi - idx.b0
-        inw = ((face == idx.face0) & (ia >= 0) & (ia < idx.W) &
-               (ib >= 0) & (ib < idx.H))
-        lidx = jnp.where(inw, ia * idx.H + ib, 0)
-        e = jnp.where(inw, idx.entry[lidx], jnp.int32(-1))
-        is_core = (e >= 0) & ((e & CORE_FLAG) != 0)
-        zone_core = jnp.where(is_core, e & ~CORE_FLAG, jnp.int32(-1))
-        is_border = (e >= 0) & ~is_core
+    def pip_dense_join(points):
+        # named scopes group the kernel's ops by stage in a profile
+        with jax.named_scope("project"):
+            if use_pallas:
+                # opt-in Pallas projection kernel (ops/pallas_projection.py)
+                # until validated on hardware; same contract, same outputs
+                from ..ops.pallas_projection import project_lattice_pallas
+                face, ai, bi, margin, facegap = project_lattice_pallas(
+                    points, idx.res,
+                    # graftlint: ignore[jit-host-sync] — idx.origin is a host-side numpy constant closed over, folds at trace time
+                    (float(idx.origin[0]), float(idx.origin[1])))
+            else:
+                face, ai, bi, margin, facegap = project_lattice_jax(
+                    points, idx.res, idx.origin, precision=precision)
+        with jax.named_scope("cell_lookup"):
+            ia = ai - idx.a0
+            ib = bi - idx.b0
+            inw = ((face == idx.face0) & (ia >= 0) & (ia < idx.W) &
+                   (ib >= 0) & (ib < idx.H))
+            lidx = jnp.where(inw, ia * idx.H + ib, 0)
+            e = jnp.where(inw, idx.entry[lidx], jnp.int32(-1))
+            is_core = (e >= 0) & ((e & CORE_FLAG) != 0)
+            zone_core = jnp.where(is_core, e & ~CORE_FLAG, jnp.int32(-1))
+            is_border = (e >= 0) & ~is_core
 
-        g = jnp.where(is_border, e, 0)
-        rec = idx.pool[g]                               # [N, E, 5]
-        ax, ay = rec[..., 0], rec[..., 1]
-        bx, by = rec[..., 2], rec[..., 3]
-        zs = rec[..., 4].astype(jnp.int32)
-        px = points[..., None, 0]
-        py = points[..., None, 1]
-        straddle = (ay <= py) != (by <= py)
-        t = (py - ay) / jnp.where(by == ay, jnp.ones_like(by), by - ay)
-        xi = ax + t * (bx - ax)
-        crossed = straddle & (px < xi)
-        near_cross = straddle & (jnp.abs(px - xi) < eps)
-        near_vertex = (jnp.abs(py - ay) < eps) & \
-            (px < jnp.maximum(ax, bx) + eps)
-        edge_flag = jnp.any(near_cross | near_vertex, axis=-1) & is_border
+        with jax.named_scope("edge_pool"):
+            g = jnp.where(is_border, e, 0)
+            rec = idx.pool[g]                           # [N, E, 5]
+            ax, ay = rec[..., 0], rec[..., 1]
+            bx, by = rec[..., 2], rec[..., 3]
+            zs = rec[..., 4].astype(jnp.int32)
+            px = points[..., None, 0]
+            py = points[..., None, 1]
+            straddle = (ay <= py) != (by <= py)
+            t = (py - ay) / jnp.where(by == ay, jnp.ones_like(by), by - ay)
+            xi = ax + t * (bx - ax)
+            crossed = straddle & (px < xi)
+            near_cross = straddle & (jnp.abs(px - xi) < eps)
+            near_vertex = (jnp.abs(py - ay) < eps) & \
+                (px < jnp.maximum(ax, bx) + eps)
+            edge_flag = jnp.any(near_cross | near_vertex, axis=-1) & \
+                is_border
 
-        inside = []
-        for z in range(Z):
-            cnt = jnp.sum(crossed & (zs == z), axis=-1)
-            inside.append((cnt & 1).astype(bool))
-        inside = jnp.stack(inside, axis=-1)             # [N, Z]
-        first = jnp.argmax(inside, axis=-1)
-        any_in = jnp.any(inside, axis=-1)
-        gz = idx.gzones[g]                              # [N, Z]
-        zone_border = jnp.where(
-            any_in & is_border,
-            jnp.take_along_axis(gz, first[..., None], axis=-1)[..., 0],
-            jnp.int32(-1))
+        with jax.named_scope("zone_parity"):
+            inside = []
+            for z in range(Z):
+                cnt = jnp.sum(crossed & (zs == z), axis=-1)
+                inside.append((cnt & 1).astype(bool))
+            inside = jnp.stack(inside, axis=-1)         # [N, Z]
+            first = jnp.argmax(inside, axis=-1)
+            any_in = jnp.any(inside, axis=-1)
+            gz = idx.gzones[g]                          # [N, Z]
+            zone_border = jnp.where(
+                any_in & is_border,
+                jnp.take_along_axis(gz, first[..., None], axis=-1)[..., 0],
+                jnp.int32(-1))
+            zone = jnp.where(is_core, zone_core, zone_border)
 
-        zone = jnp.where(is_core, zone_core, zone_border)
-        wide = idx.gwide[g] & is_border
-        uncertain = (margin < np.float32(err_lat)) | \
-            (facegap < np.float32(FACEGAP_EPS)) | edge_flag | wide
-        zone = jnp.where(far, jnp.int32(-1), zone)
-        uncertain = uncertain & ~far
+        with jax.named_scope("flags"):
+            far = (jnp.abs(points[..., 0]) > far_lim) | \
+                (jnp.abs(points[..., 1]) > far_lim)
+            wide = idx.gwide[g] & is_border
+            uncertain = (margin < np.float32(err_lat)) | \
+                (facegap < np.float32(FACEGAP_EPS)) | edge_flag | wide
+            zone = jnp.where(far, jnp.int32(-1), zone)
+            uncertain = uncertain & ~far
         return zone, uncertain
 
-    return fn
+    return pip_dense_join
 
 
 def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):

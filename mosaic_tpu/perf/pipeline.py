@@ -34,7 +34,7 @@ from typing import Callable, Iterable, List, Optional
 
 import numpy as np
 
-from ..obs import metrics
+from ..obs import metrics, tracer
 from ..resilience import faults
 
 __all__ = ["stream", "chunk_rows", "donate_jit", "staged_put"]
@@ -140,6 +140,17 @@ def stream(chunks: Iterable, compute: Callable,
     (``pipeline/observe_errors``) and flight-recorded once per stream,
     and the chunk completes normally.
 
+    Stage spans (``obs.tracer``, and ``mosaic/<span>`` in a recording
+    ``jax.profiler`` trace): the dispatch loop runs ``pipeline/put``
+    (staging, the caller's ``put`` included), ``pipeline/dispatch``
+    (``compute``) and ``pipeline/wait`` (blocked on the oldest fetch);
+    the worker runs ``pipeline/fetch`` (the device finishing the chunk,
+    then the device->host copy) and ``pipeline/consume``.  With the
+    metrics registry on, each stream adds to ``pipeline/head_s`` (entry
+    to the first dispatch), ``pipeline/tail_s`` (the last fetch's return
+    to the stream's), ``pipeline/put_s`` and ``pipeline/wait_s`` (the
+    sums of those spans).
+
     ``site`` names this stream in the device-memory ledger
     (``obs.memwatch``): each chunk's staged input registers as
     ``<site>/staged`` and its device output as ``<site>/out``, both
@@ -181,6 +192,7 @@ def stream(chunks: Iterable, compute: Callable,
     how many chunks — or how many bytes — the source will eventually
     yield."""
     import time as _time
+    t_enter = _time.perf_counter()
     import jax
     from ..obs.inflight import charge_d2h_bytes, checkpoint, inflight
     from ..obs.memwatch import device_keys_of, mem_budget, memwatch
@@ -188,11 +200,15 @@ def stream(chunks: Iterable, compute: Callable,
         put = jax.device_put
     obs_state = {"last_done": 0.0, "observe_failed": False,
                  "shrunk": False}
+    # stage clocks behind the pipeline/* counters: head and tail of
+    # the stream, and the dispatch loop's put and wait seconds
+    clock = {"head_s": 0.0, "fetched": 0.0, "put_s": 0.0, "wait_s": 0.0}
 
     def fetch(i, payload, out, dispatch_t, tok_in, tok_out):
         try:
             faults.maybe_fail("pipeline.fetch")
-            host = _to_host(out)    # blocks the WORKER until ready
+            with tracer.span("pipeline/fetch"):
+                host = _to_host(out)    # blocks the WORKER until ready
         finally:
             # the chunk's device buffers are drained — input consumed
             # by the launch, output copied out — and both must leave
@@ -201,8 +217,9 @@ def stream(chunks: Iterable, compute: Callable,
             # tokens until the query-complete sentinel swept them
             memwatch.release(tok_out)
             memwatch.release(tok_in)
+        now = _time.perf_counter()
+        clock["fetched"] = now      # single worker: the last is the tail's
         if observe is not None:     # single worker: in-order, race-free
-            now = _time.perf_counter()
             start = max(dispatch_t, obs_state["last_done"])
             obs_state["last_done"] = now
             try:
@@ -218,16 +235,25 @@ def stream(chunks: Iterable, compute: Callable,
                     recorder.record(
                         "pipeline_observe_error", chunk=i,
                         error=f"{type(exc).__name__}: {exc}")
-        if metrics.enabled or inflight._by_trace:
-            nb = _tree_bytes(host)  # device->host drain, per chunk
-            if metrics.enabled:
-                metrics.count("pipeline/d2h_bytes", nb)
-            charge_d2h_bytes(nb)    # per-query attribution
-        return consume(i, payload, host) if consume is not None \
-            else host
+        if inflight._by_trace:      # per-query device->host attribution
+            charge_d2h_bytes(_tree_bytes(host))
+        if consume is None:
+            return host
+        with tracer.span("pipeline/consume"):
+            return consume(i, payload, host)
 
     def staged(payload):
-        return staged_put(payload, site=f"{site}/staged", put=put)
+        t0 = _time.perf_counter()
+        with tracer.span("pipeline/put"):
+            dev = staged_put(payload, site=f"{site}/staged", put=put)
+        clock["put_s"] += _time.perf_counter() - t0
+        return dev
+
+    def wait(fut):
+        t0 = _time.perf_counter()
+        with tracer.span("pipeline/wait"):
+            results.append(fut.result())
+        clock["wait_s"] += _time.perf_counter() - t0
 
     # lazy source: chunks are pulled one at a time from the iterator —
     # a split pushes its halves back onto the head of this small deque,
@@ -285,7 +311,10 @@ def stream(chunks: Iterable, compute: Callable,
                 # checkpoint, one boundary later)
                 faults.stall("pipeline.chunk")
                 dispatch_t = _time.perf_counter()
-                out = compute(dev)
+                if i == 0:
+                    clock["head_s"] = dispatch_t - t_enter
+                with tracer.span("pipeline/dispatch"):
+                    out = compute(dev)
                 tok_out = memwatch.register(
                     f"{site}/out", _tree_bytes(out),
                     devices=device_keys_of(out)) \
@@ -303,10 +332,10 @@ def stream(chunks: Iterable, compute: Callable,
                 # once the window fills, so host results and queued
                 # work items stop scaling with total stream length
                 while len(futs) > _MAX_INFLIGHT_FETCHES:
-                    results.append(futs.popleft().result())
+                    wait(futs.popleft())
                 i += 1
             while futs:
-                results.append(futs.popleft().result())
+                wait(futs.popleft())
         finally:
             # a stream unwinding mid-loop (cancel, deadline, fault)
             # has staged the next chunk without dispatching it — drop
@@ -314,4 +343,10 @@ def stream(chunks: Iterable, compute: Callable,
             # leak (in-flight fetches release their own tokens as the
             # executor exit joins the worker)
             memwatch.release(tok)
+    if metrics.enabled:
+        metrics.count("pipeline/head_s", clock["head_s"])
+        metrics.count("pipeline/tail_s",
+                      _time.perf_counter() - clock["fetched"])
+        metrics.count("pipeline/put_s", clock["put_s"])
+        metrics.count("pipeline/wait_s", clock["wait_s"])
     return results
