@@ -1394,9 +1394,11 @@ def zone_histogram(zone: jnp.ndarray, num_zones: int) -> jnp.ndarray:
 # workloads that fit one icosahedron face (any city/metro/state-scale
 # join), the H3 kernel's intermediate (face, a, b) lattice coords index
 # a dense window table directly: ONE int32 gather replaces both binary
-# searches, and all chips of a cell are packed into ONE pool row so the
-# edge test is ONE more gather.  Design rule: one gather per point per
-# logical step.
+# searches, and everything a border cell needs (its chips' edges, their
+# zone slots, the cell's zone ids and its wide flag) is packed into ONE
+# lane-dense record row, so the edge test is ONE more gather and reads
+# that row in place.  Design rule: one gather per point per logical
+# step.
 
 CORE_FLAG = np.int32(1) << 30
 
@@ -1407,26 +1409,33 @@ class DensePIPIndex:
     """Device-resident dense-window tessellation index (H3, one face).
 
     entry  [W*H] i32   per lattice cell: -1 empty; CORE_FLAG|zone core;
-                       else group index into pool
-    pool   [G, E, 5]   merged chip edges per border cell, local-frame
-                       f32: ax, ay, bx, by, zslot (-1 pad; pad coords
-                       at +1e9 so they never straddle/flag)
-    gzones [G, Z] i32  distinct zone ids per group (-1 pad)
+                       else group index into rec
+    rec    [G, R] f32  one record row per border cell (group), read by
+                       one row gather per point.  R is 5*E + Z + 1
+                       rounded up to a multiple of 128 lanes; the lanes
+                       hold blocks ``ax[0:E] | ay[0:E] | bx[0:E] |
+                       by[0:E] | zslot[0:E] | gz[0:Z] | wide``: the
+                       merged chip edges in the local frame (pad coords
+                       at +1e9 so they never straddle/flag), each
+                       edge's zone slot (-1 pad), the group's distinct
+                       zone ids (-1 pad) and its wide flag (0/1).  The
+                       int lanes (zslot, gz, wide, the tail pad) hold
+                       int32 bit patterns (``lax.bitcast_convert_type``),
+                       so every zone id stays exact
     origin [2] f64     local-frame origin (lon, lat)
     static: face0, a0, b0, W, H, res, err_lattice (margin threshold),
-            n_zones
+            n_zones, E (edge slots a group), Z (zone slots a group)
     host-side aux (not traced): recheck CSR in f64 (see host_recheck_fn)
+
+    A group is wide when its chip edges exceed E (a complex coastline
+    cell): every point landing there is flagged uncertain and resolved
+    by the exact f64 host recheck, so ONE wide cell cannot pad the whole
+    table (real NYC zones: max 308 edges vs mean 19 made the kernel 12x
+    slower than the synthetic bench).
     """
 
     entry: jnp.ndarray
-    pool: jnp.ndarray
-    gzones: jnp.ndarray
-    #: [G] bool — group's chip edges exceed the pool width (a complex
-    #: coastline cell): every point landing there is flagged uncertain
-    #: and resolved by the exact f64 host recheck, so ONE wide cell
-    #: cannot pad the whole pool (real NYC zones: max 308 edges vs
-    #: mean 19 made the kernel 12x slower than the synthetic bench)
-    gwide: jnp.ndarray
+    rec: jnp.ndarray
     origin: np.ndarray
     face0: int
     a0: int
@@ -1436,16 +1445,18 @@ class DensePIPIndex:
     res: int
     err_lattice: float
     n_zones: int
+    E: int
+    Z: int
     #: max |local degree| over window cells (+ slack); join queries
     #: beyond this are out-of-domain by construction
     ext_deg: float = 2.0
     aux: Optional[dict] = None
 
     def tree_flatten(self):
-        return ((self.entry, self.pool, self.gzones, self.gwide),
+        return ((self.entry, self.rec),
                 (self.origin.tobytes(), self.face0, self.a0, self.b0,
                  self.W, self.H, self.res, self.err_lattice,
-                 self.n_zones, self.ext_deg))
+                 self.n_zones, self.E, self.Z, self.ext_deg))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -1454,7 +1465,7 @@ class DensePIPIndex:
 
     @property
     def num_chips(self) -> int:
-        return int(self.pool.shape[0])
+        return int(self.rec.shape[0])
 
 
 def _host_lattice(grid, pts_deg: np.ndarray, res: int,
@@ -1580,7 +1591,7 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
     G = len(ucells)
     gidx = np.searchsorted(ucells, b_cells)              # chip -> group
     gedges = np.bincount(gidx, weights=cnt).astype(np.int64)
-    # pool width covers the 98th-percentile group; wider groups are
+    # record width covers the 98th-percentile group; wider groups are
     # truncated and their cells flagged always-uncertain (host f64
     # resolves them exactly) — one pathological cell must not pad the
     # kernel for every point
@@ -1624,17 +1635,22 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
     np.cumsum(gedges, out=gstart[1:])
     pos = np.arange(len(flat_a)) - gstart[edge_group]
 
-    pool = np.full((max(G, 1), E, 5), 1e9, np.float32)
-    pool[..., 4] = -1.0
+    R = -(-(5 * E + Z + 1) // 128) * 128
+    rec = np.full((max(G, 1), R), 1e9, np.float32)
+    irec = rec.view(np.int32)            # the int lanes, as bit patterns
+    irec[:, 4 * E:5 * E + Z] = -1
+    irec[:, 5 * E + Z:] = 0
     loc_a = flat_a - origin[None]
     loc_b = flat_b - origin[None]
     fits = pos < E                       # wide-group overflow truncated
     eg, ep = edge_group[fits], pos[fits]
-    pool[eg, ep, 0] = loc_a[fits, 0].astype(np.float32)
-    pool[eg, ep, 1] = loc_a[fits, 1].astype(np.float32)
-    pool[eg, ep, 2] = loc_b[fits, 0].astype(np.float32)
-    pool[eg, ep, 3] = loc_b[fits, 1].astype(np.float32)
-    pool[eg, ep, 4] = edge_zslot[fits].astype(np.float32)
+    rec[eg, ep] = loc_a[fits, 0].astype(np.float32)
+    rec[eg, E + ep] = loc_a[fits, 1].astype(np.float32)
+    rec[eg, 2 * E + ep] = loc_b[fits, 0].astype(np.float32)
+    rec[eg, 3 * E + ep] = loc_b[fits, 1].astype(np.float32)
+    irec[eg, 4 * E + ep] = edge_zslot[fits]
+    irec[:G, 5 * E:5 * E + Z] = gzones
+    irec[:G, 5 * E + Z] = gwide_np
 
     prec = pick_precision(precision)
     ext_deg = float(ext) + 0.1
@@ -1657,12 +1673,10 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
         "grid": grid, "polys": polys, "sag_lattice": sag_lattice,
     }
     return DensePIPIndex(
-        entry=jnp.asarray(entry), pool=jnp.asarray(pool),
-        gzones=jnp.asarray(gzones),
-        gwide=jnp.asarray(np.resize(gwide_np, max(G, 1))),
+        entry=jnp.asarray(entry), rec=jnp.asarray(rec),
         origin=origin, face0=face0,
         a0=a0, b0=b0, W=W, H=H, res=res, err_lattice=float(err),
-        n_zones=len(polys), ext_deg=ext_deg, aux=aux)
+        n_zones=len(polys), E=E, Z=Z, ext_deg=ext_deg, aux=aux)
 
 
 def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
@@ -1682,7 +1696,7 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
     from ..core.index.h3.jaxkernel import (FACEGAP_EPS, err_lattice_bound,
                                            pick_precision,
                                            project_lattice_jax)
-    Z = int(idx.gzones.shape[1])
+    E, Z = idx.E, idx.Z
     # margin threshold must match the arithmetic that actually runs —
     # idx.err_lattice was derived at build time, possibly on another
     # backend/precision; recompute for the resolved path and take the
@@ -1732,12 +1746,16 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
 
         with jax.named_scope("edge_pool"):
             g = jnp.where(is_border, e, 0)
-            rec = idx.pool[g]                           # [N, E, 5]
-            ax, ay = rec[..., 0], rec[..., 1]
-            bx, by = rec[..., 2], rec[..., 3]
-            zs = rec[..., 4].astype(jnp.int32)
-            px = points[..., None, 0]
-            py = points[..., None, 1]
+            # one row gather a point, then the whole row turned once so
+            # points run along lanes, the layout the compiler picks for
+            # the reductions over E; each block is then a row slice read
+            # in place (lane slices of [N, R] get one relayout each)
+            rec = jnp.moveaxis(idx.rec[g], -1, 0)       # [R, N]
+            ax, ay = rec[:E], rec[E:2 * E]
+            bx, by = rec[2 * E:3 * E], rec[3 * E:4 * E]
+            zs = jax.lax.bitcast_convert_type(rec[4 * E:5 * E], jnp.int32)
+            px = points[..., 0][None]
+            py = points[..., 1][None]
             straddle = (ay <= py) != (by <= py)
             t = (py - ay) / jnp.where(by == ay, jnp.ones_like(by), by - ay)
             xi = ax + t * (bx - ax)
@@ -1745,28 +1763,26 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
             near_cross = straddle & (jnp.abs(px - xi) < eps)
             near_vertex = (jnp.abs(py - ay) < eps) & \
                 (px < jnp.maximum(ax, bx) + eps)
-            edge_flag = jnp.any(near_cross | near_vertex, axis=-1) & \
+            edge_flag = jnp.any(near_cross | near_vertex, axis=0) & \
                 is_border
 
         with jax.named_scope("zone_parity"):
-            inside = []
-            for z in range(Z):
-                cnt = jnp.sum(crossed & (zs == z), axis=-1)
-                inside.append((cnt & 1).astype(bool))
-            inside = jnp.stack(inside, axis=-1)         # [N, Z]
-            first = jnp.argmax(inside, axis=-1)
-            any_in = jnp.any(inside, axis=-1)
-            gz = idx.gzones[g]                          # [N, Z]
-            zone_border = jnp.where(
-                any_in & is_border,
-                jnp.take_along_axis(gz, first[..., None], axis=-1)[..., 0],
-                jnp.int32(-1))
-            zone = jnp.where(is_core, zone_core, zone_border)
+            tail = jax.lax.bitcast_convert_type(rec[5 * E:], jnp.int32)
+            # the zone of the first slot with odd parity: an elementwise
+            # select over the Z slots, last slot first
+            zone_border = jnp.full(is_border.shape, -1, jnp.int32)
+            for z in reversed(range(Z)):
+                cnt = jnp.sum(crossed & (zs == z), axis=0)
+                zone_border = jnp.where((cnt & 1).astype(bool), tail[z],
+                                        zone_border)
+            zone = jnp.where(is_core, zone_core,
+                             jnp.where(is_border, zone_border,
+                                       jnp.int32(-1)))
 
         with jax.named_scope("flags"):
             far = (jnp.abs(points[..., 0]) > far_lim) | \
                 (jnp.abs(points[..., 1]) > far_lim)
-            wide = idx.gwide[g] & is_border
+            wide = (tail[Z] != 0) & is_border
             uncertain = (margin < np.float32(err_lat)) | \
                 (facegap < np.float32(FACEGAP_EPS)) | edge_flag | wide
             zone = jnp.where(far, jnp.int32(-1), zone)
@@ -1803,9 +1819,9 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
     aux = idx.aux
     assert aux is not None, "recheck needs the build-time aux tables"
     entry = np.asarray(idx.entry)
-    Z = int(idx.gzones.shape[1])
+    Z = idx.Z
     # native-kernel tables, prepared ONCE at bind time (per-call work
-    # must scale with the flagged subset, not the chip-edge pool) —
+    # must scale with the flagged subset, not the record table) —
     # and only when the native path can actually run
     try:
         from .. import native as _native

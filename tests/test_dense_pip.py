@@ -1,10 +1,14 @@
-"""Dense lattice-window PIP index (parallel/pip_join.py, round 3).
+"""Dense lattice-window PIP index (parallel/pip_join.py).
 
 The dense path replaces the sorted-table binary searches (29 serial
-gathers/point measured at 56% of the TPU join) with one entry-table
-gather + one merged-chip-pool gather.  These tests pin its exactness
-contract against the float64 host oracle and its equivalence with the
-grid-agnostic sorted path.
+gathers/point measured at 56% of the TPU join) with two row gathers a
+point: the window's entry table, then one lane-dense record row of the
+point's border cell.  A row holds, in blocks of lanes, the cell's merged
+chip edges (``ax | ay | bx | by``, E each), their zone slots, the cell's
+Z zone ids and its wide flag, the int lanes as int32 bit patterns.
+These tests pin that layout against the host recheck's f64 tables, the
+exactness contract against the float64 host oracle, and the equivalence
+with the grid-agnostic sorted path.
 """
 
 import numpy as np
@@ -38,7 +42,38 @@ def dense_idx(workload):
 
 def test_dense_selected_for_city_h3(dense_idx):
     assert dense_idx.W > 10 and dense_idx.H > 10
-    assert dense_idx.pool.shape[-1] == 5
+    R = dense_idx.rec.shape[-1]
+    assert R % 128 == 0 and R >= 5 * dense_idx.E + dense_idx.Z + 1
+
+
+def test_dense_record_rows_decode_to_host_tables(dense_idx):
+    """Every group's record row holds the f32 of its host-side chip
+    edges (local frame), their zone slots, the group's zone ids and its
+    wide flag, with the pads the kernel relies on."""
+    E, Z, aux = dense_idx.E, dense_idx.Z, dense_idx.aux
+    rec = np.asarray(dense_idx.rec)
+    irec = rec.view(np.int32)
+    gstart = aux["gstart"]
+    G = len(gstart) - 1
+    assert rec.shape[0] == G and G > 0
+    ox, oy = dense_idx.origin
+    for g in range(G):
+        n = int(gstart[g + 1] - gstart[g])
+        k = min(n, E)
+        sl = slice(gstart[g], gstart[g] + k)
+        a, b = aux["flat_a"][sl], aux["flat_b"][sl]
+        want = [(a[:, 0] - ox), (a[:, 1] - oy), (b[:, 0] - ox),
+                (b[:, 1] - oy)]
+        for blk, w in enumerate(want):
+            got = rec[g, blk * E:(blk + 1) * E]
+            assert np.array_equal(got[:k], w.astype(np.float32)), (g, blk)
+            assert np.all(got[k:] == np.float32(1e9)), (g, blk)
+        zs = irec[g, 4 * E:5 * E]
+        assert np.array_equal(zs[:k], aux["edge_zslot"][sl]), g
+        assert np.all(zs[k:] == -1), g
+        assert np.array_equal(irec[g, 5 * E:5 * E + Z], aux["gzones64"][g])
+        assert irec[g, 5 * E + Z] == int(n > E), g
+        assert np.all(irec[g, 5 * E + Z + 1:] == 0), g
 
 
 def test_dense_join_matches_host_oracle(workload, dense_idx, rng):

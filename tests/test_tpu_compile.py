@@ -12,6 +12,8 @@ loads the TPU library.
 sees the CPU and would pick f64, while the chip takes df.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -67,10 +69,17 @@ def test_chunk_join_compiles_for_v5e(topo, flagship):
     one = SingleDeviceSharding(topo.devices[0])
     pts = jax.ShapeDtypeStruct((CHUNK, 2), jnp.float32, sharding=one)
     fn = make_pip_join_fn(idx, grid, precision="df")
-    mem = jax.jit(fn).lower(pts).compile().memory_analysis()
+    compiled = jax.jit(fn).lower(pts).compile()
+    mem = compiled.memory_analysis()
     # the join stays chunked: one chunk's temporaries are a small part
     # of the chip's memory
     assert 0 < mem.temp_size_in_bytes < V5E_HBM // 4
+    text = compiled.as_text()
+    # two row gathers a point: the entry table, then one record row
+    rows = re.findall(rf"= \w+\[{CHUNK}[,\]]\S* gather\(", text)
+    assert len(rows) == 2, rows
+    # the record is read as one block: no per-component [N, E, k] array
+    assert not re.search(rf"f32\[{CHUNK},{idx.E},\d+\]", text)
 
 
 def test_pallas_projection_compiles_for_v5e(topo, no_persistent_cache):
