@@ -333,8 +333,12 @@ def measure(prep: Prepared, seed: int, seconds: float, trace: bool,
             log("requests over 10x the median: " + "; ".join(
                 f"#{k} sent at {t:.3f} s took {v:.6f} s"
                 for k, t, v in slow[:20]))
+    made = feeder.made
     log(f"generator: {len(waited)} requests taken, waited {sum(waited):.6f}"
-        f" s in all, at most {max(waited, default=0.0):.6f} s")
+        f" s in all, at most {max(waited, default=0.0):.6f} s; "
+        f"{len(made)} made on {traffic.WORKERS} threads, median "
+        f"{statistics.median(made) if made else 0.0:.6f} s, at most "
+        f"{max(made, default=0.0):.6f} s each")
 
     ref = reference.Reference(zone_rings)
     t0 = time.perf_counter()
@@ -351,7 +355,7 @@ def measure(prep: Prepared, seed: int, seconds: float, trace: bool,
     record = {"timers": timers, "counters": counters, "points": points,
               "requests": len(records), "window_s": window_s,
               "rechecked": int(sum(r["rechecked"] for r in records)),
-              "trace": None}
+              "generator_wait_s": sum(waited), "trace": None}
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": memory}
     out = {"correct": correct, "attempted": sent, "failed": failed}
