@@ -250,9 +250,11 @@ def localize(idx: PIPIndex, points64: np.ndarray) -> np.ndarray:
 def make_pip_join_fn(idx, grid: IndexSystem, eps: Optional[float] = None,
                      margin_eps: Optional[float] = None,
                      precision: str = "auto"):
-    """Close the index over a jittable ``local_points -> (zone,
+    """Close the index over a jitted ``local_points -> (zone,
     uncertain)``; inputs come from ``localize`` (local-frame float32).
-    Dense indexes dispatch to make_dense_pip_join_fn.
+    Dense indexes dispatch to make_dense_pip_join_fn; the index's
+    tables are arguments of the executable, also inside a caller's own
+    jit (one that shards the points, say).
 
     ``precision`` pins the dense path's projection arithmetic ("f32" /
     "df" / "f64"; see ``h3.jaxkernel.pick_precision``).  "auto" resolves
@@ -271,9 +273,19 @@ def make_pip_join_fn(idx, grid: IndexSystem, eps: Optional[float] = None,
     f32 projection error), (c) points near the grid's domain edge.
     Out-of-domain points are forced to zone −1."""
     if isinstance(idx, DensePIPIndex):
-        return make_dense_pip_join_fn(
+        # the index is an argument of the executable, not a constant in
+        # it: a cell-keyed table is tens of MB, and as a constant it
+        # would be compiled, cached and loaded anew with every program
+        join = jax.jit(make_dense_pip_join_fn(
             idx, eps=EPS_EDGE_DEG if eps is None else eps,
-            precision=precision, margin_eps_deg=margin_eps)
+            precision=precision, margin_eps_deg=margin_eps))
+
+        def pip_dense_join(points):
+            return join(idx, points)
+
+        # as on a jitted function: the program a call runs
+        pip_dense_join.lower = lambda points: join.lower(idx, points)
+        return pip_dense_join
     # sorted-path defaults (wider: its f32 absolute-coordinate cell
     # assignment carries more error than the dense path's projection).
     # The margin additionally covers the cell-edge sagitta — the gap
@@ -307,7 +319,7 @@ def make_pip_join_fn(idx, grid: IndexSystem, eps: Optional[float] = None,
                     absolute + off) != inb
         return jnp.where(inb, zone, jnp.int32(-1)), uncertain | near_edge
 
-    return pip_sorted_join
+    return jax.jit(pip_sorted_join)
 
 
 def _resolve_chunk(chunk: Optional[int]) -> int:
@@ -345,8 +357,7 @@ def make_streamed_pip_join(idx, grid: IndexSystem,
     # can attribute the streamed join's wall time to "pip/streamed"
     fn = kernel_cache.get_or_build(
         "pip/streamed", (id(idx), id(grid), eps, margin_eps, precision),
-        lambda: jax.jit(
-            make_pip_join_fn(idx, grid, eps, margin_eps, precision)))
+        lambda: make_pip_join_fn(idx, grid, eps, margin_eps, precision))
     recheck = host_recheck_fn(idx, polys)
     origin = np.asarray(idx.origin)
     ledger_key = (id(idx), id(grid), eps, margin_eps, precision)
@@ -906,8 +917,8 @@ def make_planned_pip_join(idx, grid: IndexSystem,
             fn = kernel_cache.get_or_build(
                 "pip/monolithic",
                 (id(idx), id(grid), eps, margin_eps, precision),
-                lambda: jax.jit(make_pip_join_fn(
-                    idx, grid, eps, margin_eps, precision)))
+                lambda: make_pip_join_fn(
+                    idx, grid, eps, margin_eps, precision))
             recheck = host_recheck_fn(idx, polys)
             origin = np.asarray(idx.origin)
 
@@ -1393,14 +1404,21 @@ def zone_histogram(zone: jnp.ndarray, num_zones: int) -> jnp.ndarray:
 # gathers cost ~16-30 ns per row regardless of row width).  For H3
 # workloads that fit one icosahedron face (any city/metro/state-scale
 # join), the H3 kernel's intermediate (face, a, b) lattice coords index
-# a dense window table directly: ONE int32 gather replaces both binary
-# searches, and everything a border cell needs (its chips' edges, their
-# zone slots, the cell's zone ids and its wide flag) is packed into ONE
-# lane-dense record row, so the edge test is ONE more gather and reads
-# that row in place.  Design rule: one gather per point per logical
-# step.
+# a dense window table directly.  Everything a border cell needs (its
+# chips' edges, their zone slots, the cell's zone ids and its wide
+# flag) is packed into ONE lane-dense record row, and the record table
+# is keyed by lattice cell, with the cell's entry code in a lane of its
+# own: ONE row gather a point does the lookup and feeds the edge test
+# in place.  (A separate int32 entry gather ahead of it cost twice the
+# row gather on v5e: the table sat in VMEM, but an element gather runs
+# at ~10 cycles a point.)  Design rule: one gather per point per
+# logical step.
 
 CORE_FLAG = np.int32(1) << 30
+#: largest cell-keyed record table (bytes) a dense build lays out;
+#: a window whose table would pass it keeps one row per border group
+#: behind an int32 entry table, and pays a second gather a point
+CELL_ROWS_MAX_BYTES = 256 << 20
 
 
 @jax.tree_util.register_pytree_node_class
@@ -1408,23 +1426,34 @@ CORE_FLAG = np.int32(1) << 30
 class DensePIPIndex:
     """Device-resident dense-window tessellation index (H3, one face).
 
-    entry  [W*H] i32   per lattice cell: -1 empty; CORE_FLAG|zone core;
-                       else group index into rec
-    rec    [G, R] f32  one record row per border cell (group), read by
-                       one row gather per point.  R is 5*E + Z + 1
+    rec    [W*H, R] f32  ``layout == "cell_rows"``: one record row per
+                       window cell, at ``(a - a0) * H + (b - b0)``, read
+                       by one row gather per point.  R is 5*E + Z + 2
                        rounded up to a multiple of 128 lanes; the lanes
                        hold blocks ``ax[0:E] | ay[0:E] | bx[0:E] |
-                       by[0:E] | zslot[0:E] | gz[0:Z] | wide``: the
-                       merged chip edges in the local frame (pad coords
-                       at +1e9 so they never straddle/flag), each
-                       edge's zone slot (-1 pad), the group's distinct
-                       zone ids (-1 pad) and its wide flag (0/1).  The
-                       int lanes (zslot, gz, wide, the tail pad) hold
-                       int32 bit patterns (``lax.bitcast_convert_type``),
-                       so every zone id stays exact
+                       by[0:E] | zslot[0:E] | gz[0:Z] | wide | code``:
+                       the merged chip edges of the cell's border group
+                       in the local frame (pad coords at +1e9 so they
+                       never straddle/flag), each edge's zone slot (-1
+                       pad), the group's distinct zone ids (-1 pad), its
+                       wide flag (0/1) and the cell's entry code (-1
+                       empty; CORE_FLAG|zone core; else the group
+                       index).  A non-border cell's row is all pads but
+                       its code.  The int lanes hold int32 bit patterns
+                       (``lax.bitcast_convert_type``), so every zone id
+                       stays exact
+           [G, R] f32  ``layout == "group_rows"``: the same rows, one
+                       per border group (code = the group index), when
+                       the cell-keyed table would pass
+                       CELL_ROWS_MAX_BYTES (windows reach 64M cells)
+    entry  [W*H] i32   group rows only: the entry code per lattice
+                       cell, gathered ahead of the group's row (None in
+                       the cell-keyed layout, where the code lane holds
+                       it; the host copy is always ``aux["entry"]``)
     origin [2] f64     local-frame origin (lon, lat)
     static: face0, a0, b0, W, H, res, err_lattice (margin threshold),
-            n_zones, E (edge slots a group), Z (zone slots a group)
+            n_zones, E (edge slots a group), Z (zone slots a group),
+            groups (border groups), layout
     host-side aux (not traced): recheck CSR in f64 (see host_recheck_fn)
 
     A group is wide when its chip edges exceed E (a complex coastline
@@ -1434,7 +1463,7 @@ class DensePIPIndex:
     slower than the synthetic bench).
     """
 
-    entry: jnp.ndarray
+    entry: Optional[jnp.ndarray]
     rec: jnp.ndarray
     origin: np.ndarray
     face0: int
@@ -1450,13 +1479,16 @@ class DensePIPIndex:
     #: max |local degree| over window cells (+ slack); join queries
     #: beyond this are out-of-domain by construction
     ext_deg: float = 2.0
+    groups: int = 0
+    layout: str = "cell_rows"
     aux: Optional[dict] = None
 
     def tree_flatten(self):
         return ((self.entry, self.rec),
                 (self.origin.tobytes(), self.face0, self.a0, self.b0,
                  self.W, self.H, self.res, self.err_lattice,
-                 self.n_zones, self.E, self.Z, self.ext_deg))
+                 self.n_zones, self.E, self.Z, self.ext_deg,
+                 self.groups, self.layout))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -1465,7 +1497,7 @@ class DensePIPIndex:
 
     @property
     def num_chips(self) -> int:
-        return int(self.rec.shape[0])
+        return self.groups
 
 
 def _host_lattice(grid, pts_deg: np.ndarray, res: int,
@@ -1635,8 +1667,9 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
     np.cumsum(gedges, out=gstart[1:])
     pos = np.arange(len(flat_a)) - gstart[edge_group]
 
-    R = -(-(5 * E + Z + 1) // 128) * 128
-    rec = np.full((max(G, 1), R), 1e9, np.float32)
+    R = -(-(5 * E + Z + 2) // 128) * 128
+    # one row a group, then one pad row (what a non-border cell holds)
+    rec = np.full((G + 1, R), 1e9, np.float32)
     irec = rec.view(np.int32)            # the int lanes, as bit patterns
     irec[:, 4 * E:5 * E + Z] = -1
     irec[:, 5 * E + Z:] = 0
@@ -1651,6 +1684,22 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
     irec[eg, 4 * E + ep] = edge_zslot[fits]
     irec[:G, 5 * E:5 * E + Z] = gzones
     irec[:G, 5 * E + Z] = gwide_np
+    irec[:G, 5 * E + Z + 1] = np.arange(G, dtype=np.int32)
+    if W * H * R * 4 <= CELL_ROWS_MAX_BYTES:
+        # keyed by lattice cell: a border cell's row is its group's, any
+        # other cell's the pad row, and the code lane holds the entry
+        layout = "cell_rows"
+        border = (entry >= 0) & ((entry & CORE_FLAG) == 0)
+        rec = rec[np.where(border, entry, G)]
+        rec.view(np.int32)[:, 5 * E + Z + 1] = entry
+    else:
+        layout = "group_rows"
+        rec = rec[:max(G, 1)]
+    try:
+        from ..obs import tracer
+        tracer.count(f"dense_layout/{layout}")
+    except Exception:
+        pass
 
     prec = pick_precision(precision)
     ext_deg = float(ext) + 0.1
@@ -1670,19 +1719,23 @@ def build_dense_pip_index(polys: GeometryArray, res: int, grid,
         "flat_a": flat_a, "flat_b": flat_b,
         "edge_zslot": edge_zslot.astype(np.int64),
         "gstart": gstart, "gzones64": gzones.astype(np.int64),
+        "entry": entry,
         "grid": grid, "polys": polys, "sag_lattice": sag_lattice,
     }
     return DensePIPIndex(
-        entry=jnp.asarray(entry), rec=jnp.asarray(rec),
-        origin=origin, face0=face0,
+        entry=jnp.asarray(entry) if layout == "group_rows" else None,
+        rec=jnp.asarray(rec), origin=origin, face0=face0,
         a0=a0, b0=b0, W=W, H=H, res=res, err_lattice=float(err),
-        n_zones=len(polys), E=E, Z=Z, ext_deg=ext_deg, aux=aux)
+        n_zones=len(polys), E=E, Z=Z, ext_deg=ext_deg, groups=G,
+        layout=layout, aux=aux)
 
 
 def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
                            precision: str = "auto",
                            margin_eps_deg: Optional[float] = None):
-    """Jittable ``local_points -> (zone, uncertain)`` on the dense index.
+    """Jittable ``(idx, local_points) -> (zone, uncertain)`` on the
+    dense index ``idx`` (passed again, so that its tables can be
+    arguments of the executable; :func:`make_pip_join_fn` binds it).
 
     Exactness contract (same as the sorted path): every f32 hazard
     raises ``uncertain`` — (a) hex-boundary margin below the validated
@@ -1697,6 +1750,7 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
                                            pick_precision,
                                            project_lattice_jax)
     E, Z = idx.E, idx.Z
+    cell_rows = idx.layout == "cell_rows"
     # margin threshold must match the arithmetic that actually runs —
     # idx.err_lattice was derived at build time, possibly on another
     # backend/precision; recompute for the resolved path and take the
@@ -1719,7 +1773,7 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
         err_lat = max(err_lat, err_lattice_bound(
             idx.res, "df", idx.ext_deg, localized=True))
 
-    def pip_dense_join(points):
+    def pip_dense_join(idx, points):
         # named scopes group the kernel's ops by stage in a profile
         with jax.named_scope("project"):
             if use_pallas:
@@ -1739,18 +1793,25 @@ def make_dense_pip_join_fn(idx: DensePIPIndex, eps: float = EPS_EDGE_DEG,
             inw = ((face == idx.face0) & (ia >= 0) & (ia < idx.W) &
                    (ib >= 0) & (ib < idx.H))
             lidx = jnp.where(inw, ia * idx.H + ib, 0)
-            e = jnp.where(inw, idx.entry[lidx], jnp.int32(-1))
+            # one row gather a point, then the whole row turned once so
+            # points run along lanes, the layout the compiler picks for
+            # the reductions over E; each block is then a row slice read
+            # in place (lane slices of [N, R] get one relayout each)
+            if cell_rows:
+                rec = jnp.moveaxis(idx.rec[lidx], -1, 0)    # [R, N]
+                code = jax.lax.bitcast_convert_type(rec[5 * E + Z + 1],
+                                                    jnp.int32)
+                e = jnp.where(inw, code, jnp.int32(-1))
+            else:
+                e = jnp.where(inw, idx.entry[lidx], jnp.int32(-1))
             is_core = (e >= 0) & ((e & CORE_FLAG) != 0)
             zone_core = jnp.where(is_core, e & ~CORE_FLAG, jnp.int32(-1))
             is_border = (e >= 0) & ~is_core
 
         with jax.named_scope("edge_pool"):
-            g = jnp.where(is_border, e, 0)
-            # one row gather a point, then the whole row turned once so
-            # points run along lanes, the layout the compiler picks for
-            # the reductions over E; each block is then a row slice read
-            # in place (lane slices of [N, R] get one relayout each)
-            rec = jnp.moveaxis(idx.rec[g], -1, 0)       # [R, N]
+            if not cell_rows:
+                g = jnp.where(is_border, e, 0)
+                rec = jnp.moveaxis(idx.rec[g], -1, 0)   # [R, N]
             ax, ay = rec[:E], rec[E:2 * E]
             bx, by = rec[2 * E:3 * E], rec[3 * E:4 * E]
             zs = jax.lax.bitcast_convert_type(rec[4 * E:5 * E], jnp.int32)
@@ -1818,7 +1879,7 @@ def host_recheck_fn(idx, polys: Optional[GeometryArray] = None):
             polys)
     aux = idx.aux
     assert aux is not None, "recheck needs the build-time aux tables"
-    entry = np.asarray(idx.entry)
+    entry = aux["entry"]
     Z = idx.Z
     # native-kernel tables, prepared ONCE at bind time (per-call work
     # must scale with the flagged subset, not the record table) —

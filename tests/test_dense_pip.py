@@ -1,14 +1,17 @@
 """Dense lattice-window PIP index (parallel/pip_join.py).
 
 The dense path replaces the sorted-table binary searches (29 serial
-gathers/point measured at 56% of the TPU join) with two row gathers a
-point: the window's entry table, then one lane-dense record row of the
-point's border cell.  A row holds, in blocks of lanes, the cell's merged
-chip edges (``ax | ay | bx | by``, E each), their zone slots, the cell's
-Z zone ids and its wide flag, the int lanes as int32 bit patterns.
-These tests pin that layout against the host recheck's f64 tables, the
-exactness contract against the float64 host oracle, and the equivalence
-with the grid-agnostic sorted path.
+gathers/point measured at 56% of the TPU join) with one row gather a
+point: the record table is keyed by lattice cell, and a cell's row
+holds, in blocks of lanes, its border group's merged chip edges
+(``ax | ay | bx | by``, E each), their zone slots, the group's Z zone
+ids, its wide flag and the cell's entry code, the int lanes as int32
+bit patterns.  A window whose cell-keyed table would pass
+``CELL_ROWS_MAX_BYTES`` keeps one row per group behind an int32 entry
+table (two gathers a point).  These tests pin both layouts against the
+host recheck's f64 tables and each other, the exactness contract
+against the float64 host oracle, and the equivalence with the
+grid-agnostic sorted path.
 """
 
 import numpy as np
@@ -19,7 +22,11 @@ import pytest
 from mosaic_tpu.bench.workloads import build_workload, nyc_points
 from mosaic_tpu.core.index.factory import get_index_system
 from mosaic_tpu.core.geometry.wkt import read_wkt
+from mosaic_tpu.core.tessellate import tessellate
+from mosaic_tpu.obs import tracer
+from mosaic_tpu.parallel import pip_join
 from mosaic_tpu.parallel.pip_join import (DensePIPIndex, PIPIndex,
+                                          build_dense_pip_index,
                                           build_pip_index, host_recheck,
                                           host_recheck_fn, localize,
                                           make_pip_join_fn, pip_host_truth)
@@ -43,20 +50,34 @@ def dense_idx(workload):
 def test_dense_selected_for_city_h3(dense_idx):
     assert dense_idx.W > 10 and dense_idx.H > 10
     R = dense_idx.rec.shape[-1]
-    assert R % 128 == 0 and R >= 5 * dense_idx.E + dense_idx.Z + 1
+    assert R % 128 == 0 and R >= 5 * dense_idx.E + dense_idx.Z + 2
+    assert dense_idx.layout == "cell_rows" and dense_idx.entry is None
+    assert dense_idx.rec.shape[0] == dense_idx.W * dense_idx.H
 
 
-def test_dense_record_rows_decode_to_host_tables(dense_idx):
-    """Every group's record row holds the f32 of its host-side chip
-    edges (local frame), their zone slots, the group's zone ids and its
-    wide flag, with the pads the kernel relies on."""
-    E, Z, aux = dense_idx.E, dense_idx.Z, dense_idx.aux
-    rec = np.asarray(dense_idx.rec)
-    irec = rec.view(np.int32)
+def _is_border(entry):
+    return (entry >= 0) & ((entry & pip_join.CORE_FLAG) == 0)
+
+
+def _border_cells(idx):
+    """Lattice cell of each border group, in group order."""
+    entry = idx.aux["entry"]
+    border = np.nonzero(_is_border(entry))[0]
+    cell_of = np.empty(idx.groups, np.int64)
+    cell_of[entry[border]] = border
+    return cell_of
+
+
+def _assert_rows_decode(idx, rows):
+    """``rows[g]`` holds the f32 of group g's host-side chip edges
+    (local frame), their zone slots, the group's zone ids, its wide
+    flag and its index as code, with the pads the kernel relies on."""
+    E, Z, aux = idx.E, idx.Z, idx.aux
+    irows = rows.view(np.int32)
     gstart = aux["gstart"]
     G = len(gstart) - 1
-    assert rec.shape[0] == G and G > 0
-    ox, oy = dense_idx.origin
+    assert len(rows) == G == idx.groups and G > 0
+    ox, oy = idx.origin
     for g in range(G):
         n = int(gstart[g + 1] - gstart[g])
         k = min(n, E)
@@ -65,15 +86,79 @@ def test_dense_record_rows_decode_to_host_tables(dense_idx):
         want = [(a[:, 0] - ox), (a[:, 1] - oy), (b[:, 0] - ox),
                 (b[:, 1] - oy)]
         for blk, w in enumerate(want):
-            got = rec[g, blk * E:(blk + 1) * E]
+            got = rows[g, blk * E:(blk + 1) * E]
             assert np.array_equal(got[:k], w.astype(np.float32)), (g, blk)
             assert np.all(got[k:] == np.float32(1e9)), (g, blk)
-        zs = irec[g, 4 * E:5 * E]
+        zs = irows[g, 4 * E:5 * E]
         assert np.array_equal(zs[:k], aux["edge_zslot"][sl]), g
         assert np.all(zs[k:] == -1), g
-        assert np.array_equal(irec[g, 5 * E:5 * E + Z], aux["gzones64"][g])
-        assert irec[g, 5 * E + Z] == int(n > E), g
-        assert np.all(irec[g, 5 * E + Z + 1:] == 0), g
+        assert np.array_equal(irows[g, 5 * E:5 * E + Z], aux["gzones64"][g])
+        assert irows[g, 5 * E + Z] == int(n > E), g
+        assert irows[g, 5 * E + Z + 1] == g, g
+        assert np.all(irows[g, 5 * E + Z + 2:] == 0), g
+
+
+def test_dense_record_rows_decode_to_host_tables(dense_idx):
+    """Each border cell's row decodes to its group's host tables."""
+    rec = np.asarray(dense_idx.rec)
+    _assert_rows_decode(dense_idx, rec[_border_cells(dense_idx)])
+
+
+def test_dense_code_lane_is_entry_and_other_cells_hold_pads(dense_idx):
+    """Every window cell's code lane is the host entry code; a cell
+    that is not on a border holds the pads alone."""
+    E, Z = dense_idx.E, dense_idx.Z
+    entry = dense_idx.aux["entry"]
+    rec = np.asarray(dense_idx.rec)
+    irec = rec.view(np.int32)
+    assert np.array_equal(irec[:, 5 * E + Z + 1], entry)
+    pad = ~_is_border(entry)
+    # empty and core cells are both there to check
+    assert (entry == -1).any() and (entry != -1)[pad].any()
+    assert np.all(rec[pad, :4 * E] == np.float32(1e9))
+    assert np.all(irec[pad, 4 * E:5 * E + Z] == -1)
+    assert np.all(irec[pad, 5 * E + Z] == 0)
+    assert np.all(irec[pad, 5 * E + Z + 2:] == 0)
+
+
+def test_group_rows_layout_over_the_budget_gives_the_same_join(
+        workload, monkeypatch):
+    """A window whose cell-keyed table passes CELL_ROWS_MAX_BYTES keeps
+    one row per group behind the entry table; both layouts give the
+    same bits, and the recheck the host truth."""
+    polys, grid, res = workload
+    chips = tessellate(polys, res, grid, keep_core_geom=False)
+    tracer.reset()
+    tracer.enable()
+    try:
+        cell = build_dense_pip_index(polys, res, grid, chips=chips)
+        assert tracer.report()["counters"].get(
+            "dense_layout/cell_rows") == 1
+        table = cell.W * cell.H * cell.rec.shape[-1] * 4
+        monkeypatch.setattr(pip_join, "CELL_ROWS_MAX_BYTES", table - 1)
+        group = build_dense_pip_index(polys, res, grid, chips=chips)
+        counters = tracer.report()["counters"]
+    finally:
+        tracer.disable()
+        tracer.reset()
+    assert counters.get("dense_layout/group_rows") == 1
+    assert counters.get("dense_layout/cell_rows") == 1
+    assert cell.layout == "cell_rows" and group.layout == "group_rows"
+    assert np.array_equal(np.asarray(group.entry), cell.aux["entry"])
+    _assert_rows_decode(group, np.asarray(group.rec))
+
+    pts64 = nyc_points(50_000, seed=6)
+    out = {}
+    for idx in (cell, group):
+        fn = jax.jit(make_pip_join_fn(idx, grid))
+        zone, unc = fn(jnp.asarray(localize(idx, pts64)))
+        out[idx.layout] = (np.asarray(zone), np.asarray(unc))
+    (zc, uc), (zg, ug) = out["cell_rows"], out["group_rows"]
+    assert np.array_equal(zc, zg) and np.array_equal(uc, ug)
+    truth = pip_host_truth(pts64, polys)
+    for idx in (cell, group):
+        z, u = out[idx.layout]
+        assert np.array_equal(host_recheck_fn(idx)(pts64, z, u), truth)
 
 
 def test_dense_join_matches_host_oracle(workload, dense_idx, rng):

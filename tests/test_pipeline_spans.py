@@ -139,7 +139,8 @@ def test_stage_counters_stay_still_with_metrics_off(dense, quiet_obs):
 
 def test_dense_kernel_has_a_name_and_scopes(dense):
     idx, grid, _, _ = dense
-    lowered = jax.jit(make_pip_join_fn(idx, grid)).lower(
+    # the program a call runs: the index's tables are its arguments
+    lowered = make_pip_join_fn(idx, grid).lower(
         jax.ShapeDtypeStruct((CHUNK, 2), jnp.float32))
     text = lowered.as_text(debug_info=True)
     assert "module @jit_pip_dense_join" in text

@@ -27,6 +27,12 @@ CHUNK = 1 << 18
 V5E_HBM = 16 * 2**30
 
 
+def _tiled(a) -> int:
+    """Device bytes of a 2-D f32 array in (8, 128) tiles."""
+    rows, lanes = a.shape
+    return -(-rows // 8) * 8 * -(-lanes // 128) * 128 * 4
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -69,15 +75,23 @@ def test_chunk_join_compiles_for_v5e(topo, flagship):
     one = SingleDeviceSharding(topo.devices[0])
     pts = jax.ShapeDtypeStruct((CHUNK, 2), jnp.float32, sharding=one)
     fn = make_pip_join_fn(idx, grid, precision="df")
-    compiled = jax.jit(fn).lower(pts).compile()
+    compiled = fn.lower(pts).compile()
     mem = compiled.memory_analysis()
+    # the record table is an argument of the program, not a constant
+    assert mem.argument_size_in_bytes == CHUNK * 2 * 4 + _tiled(idx.rec)
     # the join stays chunked: one chunk's temporaries are a small part
     # of the chip's memory
     assert 0 < mem.temp_size_in_bytes < V5E_HBM // 4
     text = compiled.as_text()
-    # two row gathers a point: the entry table, then one record row
-    rows = re.findall(rf"= \w+\[{CHUNK}[,\]]\S* gather\(", text)
-    assert len(rows) == 2, rows
+    # one gather a point: the cell's record row, whose code lane is the
+    # cell lookup; no int32 entry gather ahead of it
+    assert idx.layout == "cell_rows"
+    R = idx.rec.shape[-1]
+    gathers = re.findall(rf"= \w+\[{CHUNK}[,\]]\S* gather\(", text)
+    assert gathers == re.findall(
+        rf"= f32\[{CHUNK},{R}\]\S* gather\(", text), gathers
+    assert len(gathers) == 1, gathers
+    assert not re.search(rf"= s32\[{CHUNK}\]\S* gather\(", text)
     # the record is read as one block: no per-component [N, E, k] array
     assert not re.search(rf"f32\[{CHUNK},{idx.E},\d+\]", text)
 
@@ -102,6 +116,7 @@ def test_sharded_join_step_compiles_for_4_chips(topo, flagship):
     fn = make_pip_join_fn(idx, grid, precision="df")
     mem = jax.jit(fn, in_shardings=(rows,), out_shardings=(out, out)) \
         .lower(pts).compile().memory_analysis()
-    # per-device figures: each chip holds a quarter of the points
-    assert mem.argument_size_in_bytes == CHUNK * 2 * 4 // 4
+    # per-device figures: each chip holds a quarter of the points and
+    # the whole record table
+    assert mem.argument_size_in_bytes == CHUNK * 2 * 4 // 4 + _tiled(idx.rec)
     assert 0 < mem.temp_size_in_bytes < V5E_HBM // 4
