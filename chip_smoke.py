@@ -208,7 +208,7 @@ def four_chips(seed: int, phase) -> dict:
 
     rng = np.random.default_rng(seed)
     fb = GeometryBuilder()
-    for _ in range(N_FOOTPRINTS):           # bench.py's footprints
+    for _ in range(N_FOOTPRINTS):
         cx, cy = rng.uniform(-74.2, -73.75), rng.uniform(40.55, 40.85)
         w, h = rng.uniform(2e-4, 2e-3, 2)
         fb.add_polygon(np.array([[cx - w, cy - h], [cx + w, cy - h],

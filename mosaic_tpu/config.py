@@ -42,7 +42,7 @@ MOSAIC_OBS_SLO_DUMP = "mosaic.obs.slo.dump"
 # Sampling host-profiler rate in Hz (obs/profiler.py): > 0 runs a
 # daemon thread walking sys._current_frames() at that rate, folding
 # samples into collapsed-stack counts with per-trace attribution; 0
-# (the default — off in prod, bench.py turns it on) keeps it off.
+# (the default) keeps it off.
 # Env var MOSAIC_TPU_PROFILE_HZ pins the rate over this key.
 MOSAIC_OBS_PROFILE_HZ = "mosaic.obs.profile.hz"
 # Cooldown between AUTOMATIC flight-recorder dumps (slow-query and
@@ -68,16 +68,15 @@ MOSAIC_IO_ON_ERROR = "mosaic.io.on.error"
 # empty (the default) keeps the checkout's .jax_cache/.  Env var
 # JAX_COMPILATION_CACHE_DIR takes precedence over this key.
 MOSAIC_JIT_CACHE_DIR = "mosaic.jit.cache.dir"
-# Cadence (in calls/chunks) of the sharded join's per-shard skew
-# readback and placement refresh (parallel/pip_join.py,
-# parallel/placement.py): every K-th call syncs the matched-candidate
-# counts per shard, records the shard/skew/* gauges + time series, and
-# feeds the skew-aware placement pass.
+# Cadence (in consumed chunks) of the sharded streamed join's
+# placement refresh (parallel/pip_join.py, parallel/placement.py):
+# every K-th chunk re-packs the skew-aware placement from the
+# matched-candidate density observed so far.
 MOSAIC_SHARD_SKEW_REFRESH = "mosaic.shard.skew.refresh"
 # Cost-based planner switches (sql/planner.py).  The planner is pure
 # strategy selection — results are bit-for-bit identical either way —
 # so `enabled` defaults on; force keys pin one operator's strategy
-# ("mosaic.planner.force.pip_join" = "streamed", say) for debugging
+# ("mosaic.planner.force.knn" = "ring", say) for debugging
 # or pathological workloads.
 MOSAIC_PLANNER_ENABLED = "mosaic.planner.enabled"
 MOSAIC_PLANNER_STATS_PATH = "mosaic.planner.stats.path"
@@ -268,7 +267,7 @@ class MosaicConfig:
     # Dump a flight bundle whenever an SLO objective newly breaches.
     obs_slo_dump: bool = False
     # Sampling host-profiler rate (Hz); 0 (default) = no profiler
-    # thread.  bench.py starts one explicitly for every run.
+    # thread.
     obs_profile_hz: float = 0.0
     # Minimum spacing between automatic dump-bundle writes (slow-query
     # + SLO triggers share the gate); 0 disables the cooldown.
@@ -288,9 +287,9 @@ class MosaicConfig:
     # Warm-started processes load XLA executables from disk instead of
     # recompiling.
     jit_cache_dir: str = ""
-    # Every K-th sharded-join call/chunk reads back per-shard matched
-    # counts (one host sync), records shard/skew/* and refreshes the
-    # skew-aware placement.  Smaller = fresher placement, more syncs.
+    # Every K-th chunk of a sharded streamed join re-packs the
+    # skew-aware placement.  Smaller = fresher placement, more
+    # re-packs.
     shard_skew_refresh: int = 16
     # Cost-based planner (sql/planner.py): per-query strategy choice
     # from observed stats.  Pure strategy transform — turning it off
@@ -303,7 +302,7 @@ class MosaicConfig:
     # ops/strategies validated against planner.FORCE_CHOICES.
     planner_force: tuple = ()
     # Rows per streamed-executor chunk (double-buffered device
-    # pipeline); also the planner's monolithic-vs-streamed pivot.
+    # pipeline).
     stream_chunk_rows: int = 262_144
     # "auto" | "brute" | "ring" | positive-int brute-right-max.
     knn_strategy: str = "auto"

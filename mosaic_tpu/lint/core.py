@@ -162,9 +162,8 @@ def under_with(node: ast.AST, parents: Dict[ast.AST, ast.AST],
 
 # -------------------------------------------------------------- repo
 
-#: code the AST rules walk (repo-relative prefixes / files)
+#: code the AST rules walk (repo-relative prefixes)
 CODE_ROOTS = ("mosaic_tpu",)
-CODE_FILES = ("bench.py",)
 TOOL_ROOT = "tools"
 TEST_ROOTS = ("tests", "tests_tpu")
 DOC_GLOB_DIRS = ("docs", "docs/usage", "docs/api")
@@ -191,7 +190,7 @@ class Repo:
     never touch the filesystem themselves."""
 
     def __init__(self):
-        self.modules: List[Module] = []        # mosaic_tpu + bench
+        self.modules: List[Module] = []        # mosaic_tpu
         self.tool_modules: List[Module] = []   # tools/*.py
         self.test_files: List[Tuple[str, str]] = []   # (path, text)
         self.doc_files: List[Tuple[str, str]] = []    # (path, text)
@@ -208,9 +207,6 @@ class Repo:
         for r in CODE_ROOTS:
             if os.path.isdir(os.path.join(root, r)):
                 paths.extend(_walk_py(root, r))
-        for f in CODE_FILES:
-            if os.path.isfile(os.path.join(root, f)):
-                paths.append(f)
         for p in paths:
             repo.modules.append(cls._read_module(root, p))
         if os.path.isdir(os.path.join(root, TOOL_ROOT)):

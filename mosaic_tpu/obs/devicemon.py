@@ -9,8 +9,8 @@ with per-shard matched-row counts ``w`` charges device ``i`` with
 ``seconds * w[i] / sum(w)``.  That is exactly the quantity the
 skew gauges already measure — a device holding 3x the rows of its
 peers accrues 3x the busy time — and it needs no extra host syncs:
-the weights come from readbacks the join already performs on the
-``mosaic.shard.skew.refresh`` cadence.
+the weights come from results the joins already bring back to the
+host.
 
 Feeds, all folded here:
 

@@ -6,12 +6,12 @@ Three capture modes share one report format:
 
 * **Sampling host profiler** — :class:`HostProfiler`, a daemon thread
   walking ``sys._current_frames()`` at ``mosaic.obs.profile.hz``
-  (env ``MOSAIC_TPU_PROFILE_HZ`` pins it; 0 = off, the production
-  default — bench.py turns it on for every run).  Samples fold into
-  collapsed-stack counts keyed by the active trace context of the
-  sampled thread (``obs.context`` keeps a thread-ident → trace side
-  table, because a ``ContextVar`` is not readable from another
-  thread), so two interleaved SQL queries get disjoint profiles.
+  (env ``MOSAIC_TPU_PROFILE_HZ`` pins it; 0 = off, the default).
+  Samples fold into collapsed-stack counts keyed by the active trace
+  context of the sampled thread (``obs.context`` keeps a thread-ident
+  → trace side table, because a ``ContextVar`` is not readable from
+  another thread), so two interleaved SQL queries get disjoint
+  profiles.
 * **Per-kernel device-cost ledger** — :class:`KernelLedger`, keyed by
   the same ``(name, key)`` pairs as ``perf.jit_cache.kernel_cache``.
   The streaming executor and the sharded join feed observed per-chunk

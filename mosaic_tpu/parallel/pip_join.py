@@ -431,85 +431,6 @@ def _shard_skew_readback(zones_padded: np.ndarray, D: int):
     return c
 
 
-def make_sharded_pip_join(idx, grid: IndexSystem, mesh,
-                          eps: Optional[float] = None,
-                          margin_eps: Optional[float] = None,
-                          axis: str = "data"):
-    """The multi-chip join: points shard over ``axis``, the index
-    replicates (the reference's broadcast-join regime, SURVEY.md P2).
-
-    Returns a fn points[N,2] -> (zone [N], uncertain [N]) with N
-    divisible by the mesh axis size.  Collectives only appear in
-    aggregations layered on top (see zone_histogram).  The jitted
-    kernel lives in ``perf.jit_cache.kernel_cache`` (the cached entry
-    closes over ``idx``/``mesh``, pinning both ids for the entry's
-    lifetime), so rebuilding the wrapper for the same index+mesh costs
-    a dict hit, not a retrace.
-
-    Observability: with the metrics registry enabled, the wrapper
-    records the replicated-index footprint (the broadcast-join's data
-    movement: every device holds the whole index) and, every
-    ``mosaic.shard.skew.refresh``-th call (default 16 — each readback
-    is a host sync on the hot path), the per-shard matched-candidate
-    skew (max/mean of zone >= 0 counts per shard) as both the
-    ``shard/skew/pip_join`` gauge and the ``shard/skew_series``
-    distribution.  For the skew-aware streamed composition see
-    :func:`make_sharded_streamed_pip_join`."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from ..config import default_config
-    from ..obs import metrics
-    from ..perf.jit_cache import kernel_cache
-
-    fn = make_pip_join_fn(idx, grid, eps, margin_eps)
-    pts_sharding = NamedSharding(mesh, P(axis, None))
-    out_sharding = (NamedSharding(mesh, P(axis)),
-                    NamedSharding(mesh, P(axis)))
-    jfn = kernel_cache.get_or_build(
-        "pip/sharded_wrap", (id(idx), id(mesh), axis, eps, margin_eps),
-        lambda: jax.jit(fn, in_shardings=(pts_sharding,),
-                        out_shardings=out_sharding))
-    D = mesh.shape[axis]
-    idx_bytes = sum(int(np.asarray(leaf).nbytes)
-                    for leaf in jax.tree_util.tree_leaves(idx))
-    state = {"calls": 0, "weights": None}
-
-    def wrapped(points):
-        import time as _time
-        from ..obs import tracer
-        from ..obs.context import root_trace
-        from ..obs.devicemon import devicemon, mesh_device_keys
-        with root_trace("pip_join"), tracer.span("pip_join/sharded"):
-            t0 = _time.perf_counter()
-            out = jfn(points)
-            dt = _time.perf_counter() - t0
-        from ..obs.profiler import ledger
-        ledger.observe("pip/sharded_wrap",
-                       (id(idx), id(mesh), axis, eps, margin_eps),
-                       dt, rows=int(points.shape[0]))
-        if metrics.enabled:
-            metrics.gauge("collective/replicated_index_bytes",
-                          float(idx_bytes) * D)
-            n = int(points.shape[0])
-            metrics.count("collective/points_scatter_bytes",
-                          float(points.size) * points.dtype.itemsize)
-            metrics.gauge("shard/points_per_shard/pip_join", n / D)
-            k = max(1, default_config().shard_skew_refresh)
-            if state["calls"] % k == 0:
-                if state["calls"] == 0:
-                    metrics.count("collective/broadcast_bytes",
-                                  float(idx_bytes) * max(D - 1, 1))
-                state["weights"] = \
-                    _shard_skew_readback(np.asarray(out[0]), D)
-            # charge dispatch wall time to devices by the last
-            # observed per-shard load (uniform until first readback)
-            devicemon.attribute("pip_join", dt, state["weights"],
-                                mesh_device_keys(mesh))
-            state["calls"] += 1
-        return out
-
-    return wrapped
-
-
 def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
                                    polys: Optional[GeometryArray] = None,
                                    chunk: Optional[int] = None,
@@ -530,8 +451,7 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
       −1 by construction) to ``pow2_bucket(rows / D) * D`` and the
       jitted sharded kernel is keyed into
       ``perf.jit_cache.kernel_cache`` per (index, mesh, bucket): one
-      XLA compile per bucket per mesh shape, zero in a warm process
-      (asserted by the multichip-smoke CI lane).
+      XLA compile per bucket per mesh shape, zero in a warm process.
     * **skew-aware placement** — a :class:`.placement.SkewRebalancer`
       learns per-grid-cell matched-candidate density from every
       consumed chunk (free: the zones are already on host) and, every
@@ -627,7 +547,7 @@ def make_sharded_streamed_pip_join(idx, grid: IndexSystem, mesh,
             state["recheck_s"] += _time.perf_counter() - t0
             state["rechecked"] += int(unc.sum())
             # feedback is free here — the shard results are already on
-            # host, unlike the monolithic path's cadenced device sync
+            # host
             rebalancer.observe(points64[sl], z >= 0)
             if metrics.enabled:
                 c = _shard_skew_readback(zp, D)
@@ -863,158 +783,6 @@ def make_store_sharded_pip_join(store, idx, grid: IndexSystem, mesh,
 
     run.rebalancer = rebalancer
     run.staged_bytes_by_partition = {}
-    return run
-
-
-def make_planned_pip_join(idx, grid: IndexSystem,
-                          polys: Optional[GeometryArray] = None,
-                          mesh=None,
-                          eps: Optional[float] = None,
-                          margin_eps: Optional[float] = None,
-                          precision: str = "auto",
-                          axis: str = "data"):
-    """Cost-based adaptive entry point over the whole PIP join family.
-
-    Per call the planner (sql/planner.py) picks monolithic single
-    launch vs. :func:`make_streamed_pip_join` (per chunk class) vs.
-    :func:`make_sharded_streamed_pip_join` from its learned
-    per-(strategy, size-class) cost coefficients — cold it falls back
-    to the batch-vs-chunk threshold.  Every candidate is a pure
-    strategy transform: same localize (f64 origin shift before the f32
-    cast), same jitted kernel, same f64 recheck authority, so the
-    zones are bit-for-bit identical whichever path runs.  The cheap
-    pre-pass feeds the estimate: the fraction of the point batch's
-    bbox overlapping the polygon extent bounds the matched rows.
-
-    After each call the observed wall time and matched-row count flow
-    back into the planner, so a workload's second run is planned from
-    measurement.  ``run.calibrate(points64)`` runs EVERY candidate
-    once (asserting pairwise parity) to seed the coefficients — the
-    bench's A/B sweep uses it so the crossover is learned, not guessed.
-
-    Returns ``run(points64_abs) -> (zone [N] int32, rechecked
-    count)``; ``run.last_decision`` exposes the most recent pick."""
-    import time as _time
-    from ..sql.planner import planner
-
-    variants: dict = {}
-    mesh_devices = int(np.prod(list(mesh.shape.values()))) \
-        if mesh is not None else 1
-    poly_ext = None
-    if polys is not None and len(polys):
-        bb = polys.bboxes()
-        poly_ext = (float(np.nanmin(bb[:, 0])),
-                    float(np.nanmin(bb[:, 1])),
-                    float(np.nanmax(bb[:, 2])),
-                    float(np.nanmax(bb[:, 3])))
-
-    def _variant(strategy: str, chunk: int):
-        key = (strategy, chunk if strategy == "streamed" else 0)
-        if key in variants:
-            return variants[key]
-        if strategy == "monolithic":
-            from ..perf.jit_cache import kernel_cache
-            fn = kernel_cache.get_or_build(
-                "pip/monolithic",
-                (id(idx), id(grid), eps, margin_eps, precision),
-                lambda: make_pip_join_fn(
-                    idx, grid, eps, margin_eps, precision))
-            recheck = host_recheck_fn(idx, polys)
-            origin = np.asarray(idx.origin)
-
-            def mono(points64):
-                points64 = np.asarray(points64, np.float64)[:, :2]
-                z, unc = fn(jnp.asarray(np.asarray(
-                    points64 - origin[None], np.float32)))
-                z = np.asarray(z)
-                unc = np.asarray(unc)
-                return recheck(points64, z, unc), int(unc.sum())
-
-            variants[key] = mono
-        elif strategy == "sharded":
-            variants[key] = make_sharded_streamed_pip_join(
-                idx, grid, mesh, polys=polys, chunk=chunk, eps=eps,
-                margin_eps=margin_eps, axis=axis)
-        else:
-            variants[key] = make_streamed_pip_join(
-                idx, grid, polys=polys, chunk=chunk, eps=eps,
-                margin_eps=margin_eps, precision=precision)
-        return variants[key]
-
-    def _overlap_frac(points64: np.ndarray) -> Optional[float]:
-        # bbox-overlap sketch: what fraction of the point batch's bbox
-        # intersects the polygon extent — an upper bound on match rate
-        if poly_ext is None or not len(points64):
-            return None
-        lo = points64.min(axis=0)
-        hi = points64.max(axis=0)
-        w = max(hi[0] - lo[0], 1e-12) * max(hi[1] - lo[1], 1e-12)
-        iw = max(0.0, min(hi[0], poly_ext[2]) - max(lo[0], poly_ext[0]))
-        ih = max(0.0, min(hi[1], poly_ext[3]) - max(lo[1], poly_ext[1]))
-        return min(1.0, (iw * ih) / w)
-
-    def run(points64: np.ndarray):
-        points64 = np.asarray(points64, np.float64)[:, :2]
-        n = len(points64)
-        d = planner.decide_pip_join(n, mesh_devices,
-                                    in_extent_frac=_overlap_frac(
-                                        points64))
-        strategy, chunk = d.strategy, getattr(
-            d, "chunk", planner.chunk_rows())
-        if strategy == "sharded" and mesh is None:
-            strategy = "streamed"   # forced sharded without a mesh
-        t0 = _time.perf_counter()
-        zone, rechecked = _variant(strategy, chunk)(points64)
-        planner.observe_decision(d, _time.perf_counter() - t0,
-                                 rows_out=int((zone >= 0).sum()))
-        run.last_decision = d
-        return zone, rechecked
-
-    def calibrate(points64: np.ndarray):
-        """Run every candidate once on this batch: seeds the planner's
-        coefficients AND asserts the paths agree bit-for-bit."""
-        points64 = np.asarray(points64, np.float64)[:, :2]
-        n = len(points64)
-        ref = None
-        cands = planner.pip_join_candidates(n, mesh_devices)
-        if mesh_devices > 1:
-            from ..config import default_config
-            if default_config().heat_prior:
-                # mosaic.heat.prior beyond the store-fed join: a hot
-                # skewed workload calibrates the skew-aware sharded
-                # path FIRST, so its warm-up (placement readbacks,
-                # bucket compiles) happens before any timed candidate
-                # and the learned coefficients favor the path the
-                # workload's heat says it needs.  Pure ordering hint:
-                # every candidate still runs and pairwise parity is
-                # still asserted, so results are bit-identical.
-                from ..obs import metrics
-                from ..obs.heat import heat
-                rep = heat.report(top=1)
-                if rep["tracked"] and rep["skew"] >= 2.0:
-                    cands = sorted(cands, key=lambda sc:
-                                   0 if sc[0] == "sharded" else 1)
-                    if metrics.enabled:
-                        metrics.count("heat/calibrate_hints")
-        for strategy, chunk in cands:
-            fn = _variant(strategy, chunk)
-            fn(points64)            # warm: keep compiles out of the
-            t0 = _time.perf_counter()   # learned coefficients
-            zone, _ = fn(points64)
-            wall = _time.perf_counter() - t0
-            planner.observe_op(planner.pip_cost_key(strategy, chunk),
-                               n, wall,
-                               rows_out=int((zone >= 0).sum()))
-            if ref is None:
-                ref = zone
-            elif not np.array_equal(ref, zone):
-                raise AssertionError(
-                    f"pip_join strategy {strategy!r} (chunk {chunk}) "
-                    "diverged from the reference path")
-        return ref
-
-    run.calibrate = calibrate
-    run.last_decision = None
     return run
 
 

@@ -2,16 +2,15 @@
 
 The engine has accumulated several real execution strategies for the
 same logical operator — the dict-loop vs. vectorized equi-join in
-``sql/engine.py``, brute vs. ring KNN in ``models/knn.py``, the
-monolithic vs. streamed vs. sharded PIP join family in
-``parallel/pip_join.py``, and the streamed executor's chunk classes —
-but until now every call site hard-coded its path.  This module picks
-the path per query from a cheap pre-pass (row counts, bbox overlap
-fraction) plus **observed** per-(operator, pow2 size-class) cost
-coefficients, and closes the loop after execution: estimated vs.
-actual rows and wall time feed back into the bounded coefficient
-store, so the second run of a workload plans from measurements, not
-guesses (SOLAR, arxiv 2504.01292; Adaptive Geospatial Joins, arxiv
+``sql/engine.py``, brute vs. ring KNN in ``models/knn.py``, fused
+vs. per-operator dispatch in ``perf/fusion.py``, and the refined vs.
+flat PIP join in ``parallel/pip_join.py`` — but until now every call
+site hard-coded its path.  This module picks the path per query from a
+cheap pre-pass (row counts, sampled selectivity) plus **observed**
+per-(operator, pow2 size-class) cost coefficients, and closes the loop
+after execution: estimated vs. actual rows and wall time feed back
+into the bounded coefficient store, so the second run of a workload
+plans from measurements, not guesses (SOLAR, arxiv 2504.01292; Adaptive Geospatial Joins, arxiv
 1802.09488 — the right strategy flips with cardinality/selectivity).
 
 Planner choices are **pure strategy transforms**: every candidate
@@ -63,7 +62,6 @@ MISPREDICT_FACTOR = 2.0
 FORCE_CHOICES = {
     "equi_join": ("auto", "loop", "vectorized"),
     "knn": ("auto", "brute", "ring"),
-    "pip_join": ("auto", "monolithic", "streamed", "sharded"),
     "fusion": ("auto", "on", "off"),
     "refine": ("auto", "refined", "flat"),
 }
@@ -91,7 +89,7 @@ _REFINE_PAIR_CROSSOVER = 0.5
 class Decision:
     """One strategy choice, with enough context to close the loop."""
 
-    op: str                 # plannable operator ("knn", "pip_join", ...)
+    op: str                 # plannable operator ("knn", "refine", ...)
     strategy: str           # chosen path
     reason: str             # human-readable why (EXPLAIN strategy col)
     est_rows: int = -1      # estimated input/output rows (-1 unknown)
@@ -213,13 +211,6 @@ class Planner:
         from ..config import default_config, planner_force_for
         return planner_force_for(default_config(), op)
 
-    def chunk_rows(self) -> int:
-        """The streamed executor's configured chunk size
-        (``mosaic.stream.chunk.rows``)."""
-        from ..config import default_config
-        return int(getattr(default_config(), "stream_chunk_rows",
-                           262_144))
-
     # ------------------------------------------------ coefficient store
 
     def _put(self, store: "OrderedDict", key: Tuple[str, int],
@@ -327,69 +318,6 @@ class Planner:
                    f"threshold {default_max}")
         return self.record_decision(Decision(
             "knn", s, why, n_left, cost_key=f"knn/{s}", key_n=n_left))
-
-    def pip_join_candidates(self, n: int, mesh_devices: int = 1
-                            ) -> List[Tuple[str, int]]:
-        """(strategy, chunk) candidates for an ``n``-point join —
-        every one produces bit-identical zones.  Streamed appears in
-        two chunk classes (the configured one and one 8x smaller)
-        because the throughput plateau moves with the backend."""
-        chunk = self.chunk_rows()
-        cands: List[Tuple[str, int]] = []
-        if n <= chunk:
-            cands.append(("monolithic", max(n, 1)))
-        cands.append(("streamed", chunk))
-        if chunk >= (1 << 17) and n > chunk // 8:
-            cands.append(("streamed", chunk // 8))
-        if mesh_devices > 1:
-            cands.append(("sharded", chunk))
-        return cands
-
-    @staticmethod
-    def pip_cost_key(strategy: str, chunk: int) -> str:
-        if strategy == "streamed":
-            return f"pip_join/streamed/c{int(chunk).bit_length()}"
-        return f"pip_join/{strategy}"
-
-    def decide_pip_join(self, n: int, mesh_devices: int = 1,
-                        in_extent_frac: Optional[float] = None
-                        ) -> Decision:
-        """Monolithic vs. streamed (per chunk class) vs. sharded.
-
-        ``in_extent_frac`` is the cheap bbox-overlap sketch: the
-        fraction of the point batch's bbox that intersects the
-        polygon index's extent (an upper bound on matched rows) — it
-        feeds the estimate the EXPLAIN strategy column prints."""
-        est = int(n if in_extent_frac is None
-                  else round(n * max(0.0, min(1.0, in_extent_frac))))
-        forced = self.force_for("pip_join")
-        if forced != "auto":
-            chunk = self.chunk_rows()
-            return self.record_decision(Decision(
-                "pip_join", forced, "forced by conf", est,
-                cost_key=self.pip_cost_key(forced, chunk), key_n=n,
-                forced=True))
-        cands = self.pip_join_candidates(n, mesh_devices)
-        costs = [(self.est_cost_ms(self.pip_cost_key(s, c), n), s, c)
-                 for s, c in cands]
-        known = [(ms, s, c) for ms, s, c in costs if ms is not None]
-        if known:
-            ms, s, chunk = min(known, key=lambda t: t[0])
-            why = (f"learned {ms:.3g}ms at est {_fmt_rows(est)} rows "
-                   f"({len(known)}/{len(cands)} candidates "
-                   f"calibrated)")
-        else:
-            chunk = self.chunk_rows()
-            if n <= chunk:
-                s, why = "monolithic", (f"est {_fmt_rows(est)} rows "
-                                        f"<= chunk {chunk}")
-            else:
-                s, why = "streamed", (f"est {_fmt_rows(est)} rows > "
-                                      f"chunk {chunk}")
-        d = Decision("pip_join", s, why, est,
-                     cost_key=self.pip_cost_key(s, chunk), key_n=n)
-        d.chunk = chunk           # dynamic attr: the chosen chunk rows
-        return self.record_decision(d)
 
     def decide_fusion(self, opset: str, member_ops: List[str],
                       n: int) -> Decision:

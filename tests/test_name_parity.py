@@ -6,6 +6,7 @@ here after normalizing spelling differences.  This is the VERDICT
 round-3 "name-diff returns 0 missing" gate (missing #5).
 """
 
+import os
 import re
 
 import pytest
@@ -31,6 +32,9 @@ def _reference_names():
     return {n for n in names if n not in SKIP}
 
 
+@pytest.mark.skipif(not os.path.exists(REF),
+                    reason="the reference's MosaicContext.scala is "
+                           "not present")
 def test_zero_missing_names():
     import mosaic_tpu.functions.context  # populate the registry
     import mosaic_tpu.functions.raster   # noqa: F401

@@ -200,10 +200,10 @@ def test_no_recompile_on_second_identical_run(grid):
 
 
 def test_migrated_kernels_warm_zero_compiles():
-    """The three pre-kernel_cache holdouts (overlay kernels, H3
-    candidate-sampling kernel, monolithic PIP) now build through
-    get_or_build: an identical second build must be a cache hit with
-    zero new misses, so warm runs stay at zero compiles."""
+    """The pre-kernel_cache holdouts (overlay kernels, H3
+    candidate-sampling kernel) now build through get_or_build: an
+    identical second build must be a cache hit with zero new misses,
+    so warm runs stay at zero compiles."""
     from mosaic_tpu.core.index.h3.system import H3IndexSystem
     from mosaic_tpu.parallel.overlay import (make_overlay_fn,
                                              make_overlay_pairs_fn)
@@ -382,10 +382,15 @@ def test_stream_fault_propagates(fault_plan):
         assert np.array_equal(o, np.ones(8) * i * 3.0)
 
 
-def test_streamed_pip_join_matches_unstreamed(grid):
+@pytest.mark.parametrize("chunk", [
+    2048,       # ragged last chunk
+    16384,      # one chunk larger than the batch
+    10_037,     # one chunk exactly the batch
+])
+def test_streamed_pip_join_matches_unstreamed(grid, chunk):
     """The chunked double-buffered join returns the same zones as the
     one-launch join + host recheck (chunking must not change results,
-    including at a ragged final chunk)."""
+    whether the last chunk is ragged or one chunk holds the batch)."""
     import jax
     import jax.numpy as jnp
     from mosaic_tpu.parallel.pip_join import (build_pip_index,
@@ -398,12 +403,12 @@ def test_streamed_pip_join_matches_unstreamed(grid):
     chips = tessellate(arr, 1, grid)
     idx = build_pip_index(arr, 1, grid, chips=chips)
     rng = np.random.default_rng(11)
-    pts = rng.uniform(0, 16, (10_000 + 37, 2))   # ragged last chunk
+    pts = rng.uniform(0, 16, (10_037, 2))
     join = jax.jit(make_pip_join_fn(idx, grid))
     z, u = join(jnp.asarray(localize(idx, pts)))
     ref = host_recheck_fn(idx, arr)(pts, np.asarray(z).copy(),
                                     np.asarray(u))
-    sjoin = make_streamed_pip_join(idx, grid, polys=arr, chunk=2048)
+    sjoin = make_streamed_pip_join(idx, grid, polys=arr, chunk=chunk)
     zs, rechecked = sjoin(pts)
     assert np.array_equal(zs, ref)
     assert rechecked >= 0

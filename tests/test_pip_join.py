@@ -15,7 +15,6 @@ import pytest
 from mosaic_tpu.bench.workloads import build_workload, nyc_points
 from mosaic_tpu.parallel.pip_join import (build_pip_index, host_recheck,
                                           localize, make_pip_join_fn,
-                                          make_sharded_pip_join,
                                           make_sharded_streamed_pip_join,
                                           make_streamed_pip_join,
                                           pip_host_truth,
@@ -63,34 +62,25 @@ def test_out_of_domain_points(workload):
     assert np.all(np.asarray(zone) == -1)
 
 
-def test_sharded_pip_join(workload):
-    polys, grid, res, idx = workload
-    mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
-    fn = make_sharded_pip_join(idx, grid, mesh)
-    pts64 = nyc_points(8 * 512, seed=5)
-    zone, unc = fn(jnp.asarray(localize(idx, pts64)))
-    ref_fn = jax.jit(make_pip_join_fn(idx, grid))
-    zone1, unc1 = ref_fn(jnp.asarray(localize(idx, pts64)))
-    assert np.array_equal(np.asarray(zone), np.asarray(zone1))
-    hist = zone_histogram(zone, len(polys))
-    assert int(hist.sum()) == int(np.sum(np.asarray(zone) >= 0))
-
-
-def test_sharded_streamed_parity(workload):
+@pytest.mark.parametrize("n_dev", [4, 8])
+def test_sharded_streamed_parity(workload, n_dev):
     """The sharded streamed flagship path (bucketed padding + slot
     placement + mesh sharding) is bit-for-bit the single-device
     streamed join, including a ragged final chunk not divisible by
-    the device count."""
+    the device count, on a 4-device and the full 8-device mesh."""
     polys, grid, res, idx = workload
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:n_dev]), ("data",))
     pts64 = nyc_points(10_037, seed=9)    # 3 chunks, ragged tail
     ref = make_streamed_pip_join(idx, grid, polys=polys, chunk=4096)
-    shj = make_sharded_streamed_pip_join(idx, grid, _mesh4(),
+    shj = make_sharded_streamed_pip_join(idx, grid, mesh,
                                          polys=polys, chunk=4096)
     z_ref, r_ref = ref(pts64)
     z_sh, r_sh = shj(pts64)
     assert np.array_equal(z_sh, z_ref)
     assert r_sh == r_ref
     assert np.array_equal(z_ref, pip_host_truth(pts64, polys))
+    hist = zone_histogram(z_sh, len(polys))
+    assert int(hist.sum()) == int(np.sum(z_sh >= 0))
 
 
 def test_sharded_streamed_staged_rows_per_device(workload):
@@ -210,11 +200,10 @@ def test_placement_slots_properties():
 
 
 def test_sharded_skew_refresh_conf_key(workload):
-    """Satellite: the monolithic sharded wrapper re-reads the skew on
-    the mosaic.shard.skew.refresh cadence (a time series), not just
-    on call 1."""
+    """The sharded streamed join takes its placement cadence from
+    mosaic.shard.skew.refresh: the rebalancer re-packs every K-th
+    consumed chunk, not just once."""
     from mosaic_tpu import config as cfgmod
-    from mosaic_tpu.obs import metrics
     polys, grid, res, idx = workload
     # conf-key plumbing
     cfg = cfgmod.apply_conf(cfgmod.MosaicConfig(),
@@ -224,24 +213,18 @@ def test_sharded_skew_refresh_conf_key(workload):
         cfgmod.apply_conf(cfgmod.MosaicConfig(),
                           "mosaic.shard.skew.refresh", "0")
     old = cfgmod.default_config()
-    was = metrics.enabled
-    metrics.enable()
-    h = metrics.histogram("shard/skew_series/pip_join")
-    before = h.count if h else 0
     try:
         cfgmod.set_default_config(
             dataclasses.replace(old, shard_skew_refresh=2))
-        fn = make_sharded_pip_join(idx, grid, _mesh4())
-        pts = jnp.asarray(localize(idx, nyc_points(4096, seed=13)))
-        for _ in range(5):
-            fn(pts)
+        shj = make_sharded_streamed_pip_join(idx, grid, _mesh4(),
+                                             polys=polys, chunk=1024)
+        shj(nyc_points(5 * 1024, seed=13))          # 5 chunks
     finally:
         cfgmod.set_default_config(old)
-        if not was:
-            metrics.disable()
-    h = metrics.histogram("shard/skew_series/pip_join")
-    # calls 0, 2, 4 hit the cadence -> exactly 3 new series points
-    assert h is not None and h.count - before == 3
+    r = shj.rebalancer
+    assert r.refresh == 2 and r.observations == 5
+    # chunks 2 and 4 hit the cadence -> exactly 2 re-packs
+    assert r.rebalances == 2
 
 
 def test_coarse_res_continental_join_exact():

@@ -77,6 +77,10 @@ def test_planner_force_keys():
     with pytest.raises(ConfigError):
         _config.apply_conf(cfg, "mosaic.planner.force.bogus_op",
                            "loop")
+    # the PIP join has no plannable strategies: its key selects nothing
+    with pytest.raises(ConfigError):
+        _config.apply_conf(cfg, "mosaic.planner.force.pip_join",
+                           "streamed")
     with pytest.raises(ConfigError):
         _config.apply_conf(cfg, "mosaic.planner.force.knn",
                            "warp_drive")
@@ -98,10 +102,6 @@ def test_cold_heuristics():
     assert p.decide_equi_join(100, 100).strategy == "loop"
     assert p.decide_equi_join(1 << 16, 1 << 10).strategy == \
         "vectorized"
-    d = p.decide_pip_join(100)
-    assert d.strategy == "monolithic"
-    big = p.chunk_rows() * 4
-    assert p.decide_pip_join(big).strategy == "streamed"
     assert p.decide_knn(50, 64, default_max=128).strategy == "brute"
     assert p.decide_knn(50, 10_000, default_max=128).strategy == "ring"
 

@@ -234,29 +234,3 @@ def test_explain_analyze_refine_column(conf):
     assert any("L5+1" in r for r in out["refine"])
     s.drop_table("rpts")
     s.drop_table("rz")
-
-
-def test_heat_prior_calibrate_hint(conf):
-    """mosaic.heat.prior=true reorders planned-join calibration to
-    warm the sharded path first when the heat plane reports a skewed
-    workload — an ordering hint only, answers stay bit-identical
-    (calibrate itself asserts pairwise parity)."""
-    import jax
-    from mosaic_tpu.bench.workloads import build_workload, nyc_points
-    from mosaic_tpu.obs.heat import heat
-    from mosaic_tpu.parallel.pip_join import make_planned_pip_join
-    _set("mosaic.heat.prior", "true")
-    metrics.enable()
-    heat.reset()
-    heat.touch(3, rows=100_000)     # one hot cell: skew >> 2
-    for c in range(8):
-        heat.touch(10 + c, rows=10)
-    polys, grid, res = build_workload(n_side=4, res_cells=64)
-    idx = build_pip_index(polys, res, grid)
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("data",))
-    pj = make_planned_pip_join(idx, grid, polys=polys, mesh=mesh)
-    c0 = metrics.counter_value("heat/calibrate_hints")
-    pts = nyc_points(4_096, seed=71)
-    pj.calibrate(pts)               # raises on any pairwise mismatch
-    assert metrics.counter_value("heat/calibrate_hints") == c0 + 1
-    heat.reset()

@@ -6,8 +6,8 @@ spawn/ready bookkeeping, crash detection + backoff respawn, the
 crash-loop circuit breaker, SIGTERM drain with the bounded hard-kill
 path, and the ``serve.spawn`` fault site — is exercised in
 milliseconds.  The end-to-end fleet (real ``QueryServer`` workers,
-kill drill, warm-cache respawn) runs in bench.py's fleet stage and
-the fleet-chaos CI lane.
+kill drill, warm-cache respawn) runs in the fleet-smoke and
+fleet-chaos CI lanes.
 """
 
 import json

@@ -1,6 +1,5 @@
 """Telemetry time-series store: rollup math, bounded memory,
-snapshot/restore, the background sampler lifecycle, and the bench
-watchdog's trajectory analysis.
+snapshot/restore, and the background sampler lifecycle.
 
 The rollup tests compare windowed reads against brute force over the
 original point stream — levels strictly partition time, so a windowed
@@ -10,8 +9,6 @@ only while the window sits inside the raw ring).
 """
 
 import json
-import os
-import sys
 import time
 
 import pytest
@@ -28,10 +25,6 @@ from mosaic_tpu.obs.timeseries import (BUCKET_CAP, MAX_SERIES, RAW_CAP,
                                        Sampler, Series, TimeSeriesStore,
                                        configure_sampler, sampler,
                                        start_sampler, stop_sampler)
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-from tools import bench_watchdog  # noqa: E402
 
 
 @pytest.fixture
@@ -241,91 +234,3 @@ def test_env_var_pins_cadence(clean_sampler, monkeypatch):
     monkeypatch.setenv("MOSAIC_TPU_OBS_SAMPLE_MS", "250")
     configure_sampler(30.0)                  # ignored while pinned
     assert sampler() is None
-
-
-# ----------------------------------------------------- bench watchdog
-
-def test_watchdog_tolerates_thin_history():
-    r = bench_watchdog.analyze([], {"device_ms": 100.0})
-    assert r["status"] == "no-history" and r["flags"] == []
-    r = bench_watchdog.analyze([("1", {"device_ms": 100.0})],
-                               {"device_ms": 101.0})
-    assert r["status"] == "short-history" and r["flags"] == []
-
-
-def test_watchdog_flags_regressions_both_directions():
-    hist = [(str(i), {"device_ms": 100.0 + i, "value": 1000.0})
-            for i in range(5)]
-    r = bench_watchdog.analyze(hist, {"device_ms": 160.0,
-                                      "value": 700.0})
-    assert any(m.startswith("device_ms") for m in r["regressions"])
-    assert any(m.startswith("value") for m in r["regressions"])
-    assert any("device_ms" in m for m in r["variance_spikes"])
-    clean = bench_watchdog.analyze(hist, {"device_ms": 103.0,
-                                          "value": 1010.0})
-    assert clean["flags"] == []
-
-
-def test_watchdog_markdown_report():
-    hist = [(str(i), {"end_to_end_ms": 50.0}) for i in range(3)]
-    r = bench_watchdog.analyze(hist, {"end_to_end_ms": 49.0})
-    md = bench_watchdog.to_markdown(r, platform="cpu")
-    assert "# Bench watchdog (cpu)" in md
-    assert "| end_to_end_ms |" in md and "- none" in md
-
-
-def test_watchdog_unwraps_runner_records(tmp_path):
-    inner = {"metric": "pip_join_points_per_sec", "platform": "cpu",
-             "device_ms": 123.0}
-    wrapper = {"n": 1, "cmd": "python bench.py", "rc": 0,
-               "tail": "noise line\n" + json.dumps(inner) + "\n"}
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps(wrapper, indent=2))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(inner))
-    hist = bench_watchdog.load_history(str(tmp_path), "cpu")
-    assert [t for t, _ in hist] == ["01", "02"]
-    assert all(r["device_ms"] == 123.0 for _, r in hist)
-
-
-def test_watchdog_metric_lists_match_bench_guard():
-    """The watchdog keeps local copies of the perf-guard direction
-    lists; this pins them to the literals in bench.py."""
-    import ast
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    tree = ast.parse(open(os.path.join(root, "bench.py")).read())
-    found = {}
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign) and \
-                isinstance(node.targets[0], ast.Name) and \
-                node.targets[0].id in ("lower_better", "higher_better"):
-            found[node.targets[0].id] = ast.literal_eval(node.value)
-    assert found["lower_better"] == bench_watchdog.LOWER_BETTER
-    assert found["higher_better"] == bench_watchdog.HIGHER_BETTER
-
-
-def test_watchdog_store_metrics_guard_after_two_rounds():
-    """``store.*`` metrics trend from their first record but only
-    join the 20% guard once TWO history rounds carry the key — the
-    first round of a new bench stage must not hard-fail the guard,
-    and the gated keys stay out of the pinned bench lists."""
-    assert not (set(bench_watchdog.GUARD_AFTER_HISTORY)
-                & set(bench_watchdog.LOWER_BETTER
-                      + bench_watchdog.HIGHER_BETTER
-                      + bench_watchdog.TREND_ONLY))
-    bad = {"store": {"ingest_s": 20.0, "query_pts_per_s": 100.0}}
-    ok = {"store": {"ingest_s": 10.0, "query_pts_per_s": 1000.0}}
-    one = [("1", ok)]
-    r = bench_watchdog.analyze(one, bad)
-    assert r["regressions"] == []            # 1 round: trend only
-    assert r["trends"]["store.ingest_s"]["direction"] == "trend"
-    assert r["trends"]["store.ingest_s"]["history"] == [10.0]
-    two = [("1", ok), ("2", ok)]
-    r = bench_watchdog.analyze(two, bad)     # 2 rounds: guard armed
-    assert any(m.startswith("store.ingest_s")
-               for m in r["regressions"])
-    assert any(m.startswith("store.query_pts_per_s")
-               for m in r["regressions"])
-    assert r["trends"]["store.ingest_s"]["direction"] == \
-        "lower_better"
-    clean = bench_watchdog.analyze(two, ok)
-    assert clean["regressions"] == []

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Load generator for the mosaic_tpu query server (serve/).
 
-Importable (bench.py's ``serving`` record block and the CI smoke lane
-call :func:`run_loadtest` / :func:`deadline_curve` in-process) and a
-CLI::
+Importable (the serve-smoke and fleet-chaos CI lanes call
+:func:`run_loadtest` in-process) and a CLI::
 
     python tools/loadtest.py --url http://127.0.0.1:8817 \
         --clients 8 --duration 3 --sql "SELECT count(*) FROM pts"
